@@ -30,5 +30,8 @@ def _apply(kind, p, x, act_levels):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     for r in run():
         print(",".join(r))

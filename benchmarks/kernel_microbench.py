@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import autotune, ops, ref
+from repro.launch.compile_cache import use_compile_cache
 
 # The serving model's contraction sites (d_model=128, d_ff=256 config used
 # by tests and BENCH_serve):  (K, N) in {attn proj, ffn up, ffn down} ×
@@ -214,6 +215,7 @@ def main(argv=None):
                     help="output path (default: baseline path, or "
                          "BENCH_kernels.smoke.json under --smoke)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     result = run_bench(calls=args.calls, reps=args.reps, seed=args.seed)
     ok = True

@@ -79,5 +79,8 @@ def run(steps=250):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     for r in run():
         print(",".join(r))

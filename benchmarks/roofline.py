@@ -112,5 +112,8 @@ def run(results_dir: str = "dryrun_results"):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     for r in run():
         print(",".join(r))

@@ -19,6 +19,9 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
     scale = 3 if args.full else 1
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from benchmarks import (fig2_parabola, fig6_mnist, fig7_autoencoder,
                             memory_savings, roofline, table1_alexnet,

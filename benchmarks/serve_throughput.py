@@ -82,6 +82,7 @@ import numpy as np
 import repro.configs as configs
 from repro.core.export import kv_cache_bytes
 from repro.core.quantizer import WeightQuantConfig, cluster_params, init_state
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model_zoo import build
 from repro.serving import ServeEngine, SpecConfig, to_codebook_params
 
@@ -459,7 +460,9 @@ def tp_child(model, cfg, params, args) -> dict:
 def run_tp(args) -> int:
     """Fan --tp degrees out to subprocesses (XLA's device count is fixed at
     backend init, so each degree gets its own process) and merge the rows
-    into --json-out without disturbing the full-run payload."""
+    into --json-out without disturbing the full-run payload.  Runs in a
+    parent that has made no jax call: the children run one at a time, and
+    each is the only process holding the devices."""
     rows = []
     for tp in args.tp:
         if args.page_size % tp:
@@ -556,6 +559,14 @@ def main():
     ap.add_argument("--json-out", default="BENCH_serve.json",
                     help="machine-readable results path ('' disables)")
     args = ap.parse_args()
+    if args.tp:
+        # decided before ANY jax call: each degree's child must be the
+        # only process holding the devices, so this parent never opens them
+        if args.smoke:
+            ap.error("--tp is a standalone mode (per-degree subprocesses); "
+                     "run --smoke separately so its gate actually executes")
+        sys.exit(run_tp(args))
+    use_compile_cache()
 
     cfg = configs.get(args.arch).reduced().replace(n_layers=args.layers,
                                                    dtype="float32")
@@ -567,11 +578,6 @@ def main():
     if args.tp_child:
         print(_TP_SENTINEL + json.dumps(tp_child(model, cfg, params, args)))
         return
-    if args.tp:
-        if args.smoke:
-            ap.error("--tp is a standalone mode (per-degree subprocesses); "
-                     "run --smoke separately so its gate actually executes")
-        sys.exit(run_tp(args))
     if args.smoke:
         sys.exit(smoke(model, cfg, params, rng, args.json_out,
                        seed=args.seed, arch=args.arch))

@@ -146,6 +146,15 @@ def _centers(values: jnp.ndarray, cfg: WeightQuantConfig, k: int,
     return clustering.kmeans1d(v, k, iters=cfg.kmeans_iters)
 
 
+@jax.jit
+def _concat_f32(leaves):
+    """Every leaf as one flat f32 vector.  Jitted so the f32 casts fuse
+    into the concatenation: eagerly, each leaf's f32 copy would exist
+    beside the result (2× the network in f32 at once)."""
+    return jnp.concatenate([v.reshape(-1).astype(jnp.float32)
+                            for v in leaves])
+
+
 def cluster_params(params: PyTree, cfg: WeightQuantConfig,
                    state: QuantizerState, step: int,
                    key: jax.Array) -> tuple[PyTree, QuantizerState]:
@@ -160,10 +169,11 @@ def cluster_params(params: PyTree, cfg: WeightQuantConfig,
     paths, values, treedef = _flat_paths(params)
 
     if cfg.scope == "global":
-        included = [v.reshape(-1).astype(jnp.float32)
-                    for p, v in zip(paths, values) if keep(p)]
-        flat = jnp.concatenate(included) if included else jnp.zeros((1,), jnp.float32)
+        included = [v for p, v in zip(paths, values) if keep(p)]
+        flat = (_concat_f32(included) if included
+                else jnp.zeros((1,), jnp.float32))
         centers = _centers(flat, cfg, k, key)
+        del flat          # the f32 copy of the whole network: 4 B/weight
         new_values = [
             clustering.quantize_to_centers(v, centers) if keep(p) else v
             for p, v in zip(paths, values)

@@ -1,14 +1,9 @@
-"""JAX version compatibility for the sharding API surface.
+"""The sharding API spellings the repo uses, in one place.
 
-The repo targets the jax 0.4.37 pin (requirements.txt) but was written
-against the newer spellings; these shims accept both:
-
-* ``make_mesh(shape, axes)`` — newer jax takes ``axis_types=(AxisType.Auto,
-  ...)``; 0.4.x has neither the kwarg nor the enum (Auto is the default
-  behaviour there anyway).
-* ``shard_map(...)`` — top-level ``jax.shard_map`` with ``check_vma=``
-  landed after 0.4.x; the older home is ``jax.experimental.shard_map``
-  with the flag spelled ``check_rep=``.
+* ``make_mesh(shape, axes)`` — every axis ``AxisType.Auto`` (the compiler
+  propagates shardings; nothing here uses explicit-sharding mode).
+* ``shard_map(...)`` — ``jax.shard_map`` with replication checking off by
+  default: the Pallas kernels inside have no replication rule.
 """
 
 from __future__ import annotations
@@ -18,18 +13,12 @@ import jax
 __all__ = ["make_mesh", "shard_map"]
 
 
-def make_mesh(shape, axes):
-    AxisType = getattr(jax.sharding, "AxisType", None)
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -1,10 +1,11 @@
 """Deterministic, cache-backed kernel autotuning (DESIGN.md §12).
 
 Every routed contraction (``ops.codebook_matmul`` / ``ops.lut_matmul``)
-asks this module for its launch config at trace time — block sizes and
-unroll for the compiled Pallas kernels on TPU, chunking/variant for the
-XLA fallbacks elsewhere.  Shapes are static under jit, so the lookup
-happens once per traced shape and folds into the executable.
+asks this module for its launch config at trace time — block sizes for
+the compiled Pallas codebook kernel on TPU, chunking/variant for the XLA
+routes (every lut contraction, and codebook off-TPU).  Shapes are static
+under jit, so the lookup happens once per traced shape and folds into the
+executable.
 
 Selection is a *deterministic cost model* over the candidate space —
 padded-tile memory traffic in integer bytes, largest-tile-first
@@ -90,39 +91,36 @@ def candidates(kernel: str, plat: str, m: int, k: int, n: int,
                dtype: str, table_shape: tuple) -> list:
     """Enumerate valid launch configs, preferred-first.
 
-    tpu: (bm, bn, bk) Pallas tiles — bn/bk are lane-dim multiples of 128,
-         bm multiples of the f32 sublane (8); everything that must be
-         VMEM-co-resident (3 live tiles, double-buffered streams, the
-         whole table) has to fit the budget.  lut adds the K-step unroll.
+    tpu: codebook — (bm, bn, bk) Pallas tiles: bn/bk are lane-dim
+         multiples of 128, bm multiples of the f32 sublane (8); everything
+         that must be VMEM-co-resident (double-buffered streams, the
+         accumulator, the in-kernel dequantize temporaries, the codebook)
+         has to fit the budget.  lut — the XLA candidates below: the
+         Pallas lut kernel gathers from the whole flattened table, and
+         Mosaic lowers a gather only within one 128-lane vreg, so the
+         compiled route on a TPU is the XLA gather-accumulate.
     xla: lut — gather variant ('rows' | 'flat') × K-chunk size; codebook —
          a single fused gather+dot, nothing to tune.
     """
+    if kernel == "lut":
+        return [{"impl": "xla", "variant": v, "kc": kc}
+                for v in ("rows", "flat") for kc in (32, 64, 128)]
     if plat == "xla":
-        if kernel == "lut":
-            return [{"impl": "xla", "variant": v, "kc": kc}
-                    for v in ("rows", "flat") for kc in (32, 64, 128)
-                    ]
         return [{"impl": "xla"}]
 
-    table_bytes = 4
-    for d in table_shape:
-        table_bytes *= int(d)
-    in_bytes = 4 if kernel == "lut" else (2 if dtype == "bfloat16" else 4)
+    book_bytes = 4 * _ceil_to(int(table_shape[-1]), _LANE)
+    in_bytes = 2 if dtype == "bfloat16" else 4
     out = []
     for bm in _tile_sizes(m, _SUBLANE, 256):
         for bn in _tile_sizes(n, _LANE, 512):
             for bk in _tile_sizes(k, _LANE, 512):
-                # 2× on the streamed operands: double-buffered DMA windows
+                # 2× on the streamed operands: double-buffered DMA windows;
+                # 3 int32/f32 (bk, bn) planes: ids, segment ids, weights
                 vmem = (2 * (bm * bk + bk * bn) * in_bytes
-                        + bm * bn * 4 + table_bytes)
-                if kernel == "lut":
-                    vmem += bm * bn * 4          # gathered unroll tile
+                        + bm * bn * 4 + 3 * bk * bn * 4 + book_bytes)
                 if vmem > _VMEM_BUDGET:
                     continue
-                cfg = {"impl": "pallas", "bm": bm, "bn": bn, "bk": bk}
-                if kernel == "lut":
-                    cfg["unroll"] = 8
-                out.append(cfg)
+                out.append({"impl": "pallas", "bm": bm, "bn": bn, "bk": bk})
     return out
 
 
@@ -144,9 +142,6 @@ def model_cost(kernel: str, cfg: dict, m: int, k: int, n: int,
     A/B timings on the serving shapes (DESIGN.md §12), not first
     principles — the committed tuning cache pins the hot shapes anyway.
     """
-    table_bytes = 4
-    for d in table_shape:
-        table_bytes *= int(d)
     if cfg.get("impl") == "xla":
         if kernel != "lut":
             return 0
@@ -163,10 +158,10 @@ def model_cost(kernel: str, cfg: dict, m: int, k: int, n: int,
         return gather + steps * 50_000 + spill
     bm, bn, bk = int(cfg["bm"]), int(cfg["bn"]), int(cfg["bk"])
     gm, gn, gk = -(-m // bm), -(-n // bn), -(-k // bk)
-    in_bytes = 4 if kernel == "lut" else (2 if dtype == "bfloat16" else 4)
+    in_bytes = 2 if dtype == "bfloat16" else 4
     stream = gm * gn * gk * (bm * bk + bk * bn) * in_bytes
     out_pass = gm * gn * bm * bn * 4
-    return stream + out_pass + table_bytes
+    return stream + out_pass + 4 * int(table_shape[-1])
 
 
 def _measure(kernel: str, cfg: dict, m: int, k: int, n: int,
@@ -184,14 +179,9 @@ def _measure(kernel: str, cfg: dict, m: int, k: int, n: int,
         a = jnp.asarray(rng.integers(0, r, (m, k)), jnp.int32)
         w = jnp.asarray(rng.integers(0, c, (k, n)), jnp.int32)
         t = jnp.asarray(rng.integers(-1000, 1000, (r, c)), jnp.int32)
-        from repro.kernels.lut_matmul import lut_matmul_pallas, lut_matmul_xla
-        if cfg.get("impl") == "xla":
-            fn = lambda: lut_matmul_xla(a, w, t, kc=cfg["kc"],   # noqa: E731
-                                        variant=cfg["variant"])
-        else:
-            fn = lambda: lut_matmul_pallas(                      # noqa: E731
-                a, w, t, bm=cfg["bm"], bn=cfg["bn"], bk=cfg["bk"],
-                unroll=cfg.get("unroll", 8), interpret=False)
+        from repro.kernels.lut_matmul import lut_matmul_xla
+        fn = lambda: lut_matmul_xla(a, w, t, kc=cfg["kc"],       # noqa: E731
+                                    variant=cfg["variant"])
     else:
         w_ = int(table_shape[-1])
         x = jnp.asarray(rng.standard_normal((m, k)),

@@ -6,8 +6,13 @@ to 65536) while the |W|-entry f32/bf16 codebook is tiny and VMEM-resident.
 Each grid step:
 
   HBM → VMEM   x tile (bm×bk, bf16/f32) and w_idx tile (bk×bn, int8/16)
-  VMEM         gather: w = codebook[w_idx]   (VPU)
+  VMEM         gather: w = codebook[w_idx]   (VPU lane gathers, below)
   MXU          acc += x_tile @ w_tile        (f32 accumulation)
+
+Mosaic lowers a gather only within one 128-lane vreg, so the codebook
+rides as an (S, 128) block (S = ⌈|W|/128⌉) and each id splits into a
+segment and a lane: S ``take_along_axis`` lane gathers plus selects per
+128 columns (``_dequantize``).
 
 HBM weight traffic drops 2–4× vs bf16 (4–8× vs f32), which is the roofline
 win for memory-bound decode shapes.  The multiply itself is free on the MXU —
@@ -52,8 +57,36 @@ def _canonical_idx(idx, n: int):
     return jnp.where(idx < 0, idx + n, idx)
 
 
+_LANES = 128    # one vreg row: the widest gather Mosaic lowers
+
+
+def _dequantize(idx, book, n_book: int):
+    """w[k, n] = codebook[idx[k, n]] with the codebook held as (S, 128).
+
+    Mosaic lowers a gather only inside one 128-lane vreg, so each id splits
+    into a segment ``hi = id // 128`` and a lane ``lo = id % 128``: every
+    segment is one lane gather (``take_along_axis`` over a row-broadcast
+    of that segment), and a select keeps the segment each id points into.
+    S = ⌈|W| / 128⌉ gathers per 128 columns — 8 at the paper's |W|=1000.
+    """
+    idx = jnp.clip(_canonical_idx(idx, n_book), 0, n_book - 1)
+    hi, lo = idx // _LANES, idx % _LANES
+    rows, cols = idx.shape
+    cols_out = []
+    for c in range(0, cols, _LANES):                     # static unroll
+        lo_c, hi_c = lo[:, c:c + _LANES], hi[:, c:c + _LANES]
+        w = jnp.zeros(lo_c.shape, jnp.float32)
+        for s in range(book.shape[0]):
+            seg = jnp.broadcast_to(book[s:s + 1, :], (rows, _LANES))
+            g = jnp.take_along_axis(seg, lo_c, axis=1)
+            w = jnp.where(hi_c == s, g, w)
+        cols_out.append(w)
+    return cols_out[0] if len(cols_out) == 1 else jnp.concatenate(cols_out,
+                                                                  axis=1)
+
+
 def codebook_matmul_kernel(x_ref, idx_ref, book_ref, out_ref, *,
-                           bk: int, k_total: int):
+                           bk: int, k_total: int, n_book: int):
     """One (bm, bn) f32 accumulator tile; revisited across the K grid."""
     kg = pl.program_id(2)
 
@@ -61,17 +94,18 @@ def codebook_matmul_kernel(x_ref, idx_ref, book_ref, out_ref, *,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    idx = idx_ref[...].astype(jnp.int32)           # (bk, bn)
-    book = book_ref[0, :]                          # (|W|,) — VMEM-resident
-    w = jnp.take(book, jnp.clip(idx, 0, book.shape[0] - 1), axis=0,
-                 mode="clip")                      # dequantize in VMEM
-    # explicit ragged-K masks on BOTH operands: an edge block's undefined
-    # lanes (TPU) might be NaN, and NaN·0 would poison the accumulator
-    kw = jax.lax.broadcasted_iota(jnp.int32, w.shape, 0) + kg * bk
-    w = jnp.where(kw < k_total, w, 0.0)
+    # dequantize in VMEM from the narrow ids (canonicalised in-kernel, so
+    # HBM only ever holds the int8/int16 planes)
+    w = _dequantize(idx_ref[...].astype(jnp.int32), book_ref[...], n_book)
     x = x_ref[...]
-    kx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + kg * bk
-    x = jnp.where(kx < k_total, x, jnp.zeros_like(x))
+    if k_total % bk:
+        # explicit ragged-K masks on BOTH operands: an edge block's
+        # undefined lanes (TPU) might be NaN, and NaN·0 would poison the
+        # accumulator
+        kw = jax.lax.broadcasted_iota(jnp.int32, w.shape, 0) + kg * bk
+        w = jnp.where(kw < k_total, w, 0.0)
+        kx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + kg * bk
+        x = jnp.where(kx < k_total, x, jnp.zeros_like(x))
     out_ref[...] += jnp.dot(x, w.astype(x.dtype),
                             preferred_element_type=jnp.float32)
 
@@ -89,28 +123,31 @@ def codebook_matmul_pallas(x: jnp.ndarray, w_idx: jnp.ndarray,
     m, k = x.shape
     k2, n = w_idx.shape
     assert k == k2, (x.shape, w_idx.shape)
-    w_can = _canonical_idx(w_idx, codebook.shape[-1])
-    book2d = codebook.reshape(1, -1).astype(jnp.float32)
+    n_book = codebook.shape[-1]
+    n_seg = pl.cdiv(n_book, _LANES)
+    book2d = jnp.pad(codebook.astype(jnp.float32).reshape(-1),
+                     (0, n_seg * _LANES - n_book)).reshape(n_seg, _LANES)
 
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk))
     kwargs = {}
     if not interpret:       # TPU: m,n parallel; K revisits the accumulator
         from jax.experimental.pallas import tpu as pltpu
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
-        functools.partial(codebook_matmul_kernel, bk=bk, k_total=k),
+        functools.partial(codebook_matmul_kernel, bk=bk, k_total=k,
+                          n_book=n_book),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, book2d.shape[1]), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec((n_seg, _LANES), lambda i, j, kk: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
         **kwargs,
-    )(x, w_can, book2d)
+    )(x, w_idx, book2d)
     return out
 
 

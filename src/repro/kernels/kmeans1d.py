@@ -40,7 +40,7 @@ def kmeans_assign_kernel(v_ref, b_ref, idx_ref, sums_ref, counts_ref, *,
     idx = jnp.zeros_like(v, dtype=jnp.int32)
     n_chunks = bounds.shape[0] // _CHUNK
     for c in range(n_chunks):                       # static unroll
-        chunk = jax.lax.dynamic_slice_in_dim(bounds, c * _CHUNK, _CHUNK)
+        chunk = bounds[c * _CHUNK:(c + 1) * _CHUNK]  # static slice
         idx += jnp.sum(v[:, None] >= chunk[None, :], axis=1).astype(jnp.int32)
     idx_ref[0, :] = idx
 
@@ -49,14 +49,19 @@ def kmeans_assign_kernel(v_ref, b_ref, idx_ref, sums_ref, counts_ref, *,
     for c in range(kc):                             # static unroll
         ids = c * _CHUNK + jax.lax.broadcasted_iota(jnp.int32, (1, _CHUNK), 1)
         mask = (idx[:, None] == ids).astype(jnp.float32)       # (bv, 128)
-        sums_ref[0, c * _CHUNK:(c + 1) * _CHUNK] += v @ mask
-        counts_ref[0, c * _CHUNK:(c + 1) * _CHUNK] += jnp.sum(mask, axis=0)
+        sl = slice(c * _CHUNK, (c + 1) * _CHUNK)
+        sums_ref[:, sl] += jnp.dot(v[None, :], mask,         # 2-D for MXU
+                                   preferred_element_type=jnp.float32)
+        counts_ref[:, sl] += jnp.sum(mask, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("bv", "interpret"))
 def kmeans_assign_pallas(values: jnp.ndarray, centers: jnp.ndarray, *,
-                         bv: int = 4096, interpret: bool = True):
+                         bv: int = 1024, interpret: bool = True):
     """values: (n,) float; centers: (k,) sorted.  Returns (idx, sums, counts).
+
+    ``bv`` = 1024 keeps the unrolled (bv, 128) compare / one-hot
+    temporaries inside the TPU's 16 MiB of scoped VMEM (4096 needs ~31 MiB).
 
     Padding: values padded with +inf (assigned to the last center) and the
     pad contribution removed from sums/counts afterwards; boundaries padded

@@ -26,10 +26,11 @@ The K loop walks ``unroll`` steps per ``fori_loop`` iteration so the
 gathered intermediate is ``unroll`` (bm, bn) tiles rather than a
 (bm, bk, bn) cube — VMEM stays bounded by 3 tiles + the table.
 
-On a real TPU this runs on the VPU (gathers + int adds; the MXU is idle) —
-it is the *faithful artifact* proving the multiply-free dataflow, not the
-deployment path (that is ``codebook_matmul``, DESIGN.md §2).  Off-TPU the
-serving path takes ``lut_matmul_xla`` below — the same gather-accumulate
+The kernel is the *faithful artifact* of the multiply-free dataflow, run
+in Pallas interpret mode; it has no Mosaic lowering — its gather into the
+flattened |A|·|W| table spans far more than the one 128-lane vreg a TPU
+kernel may gather within (DESIGN.md §12).  Every serving platform, the
+TPU included, takes ``lut_matmul_xla`` below — the same gather-accumulate
 contraction expressed as XLA ops (bit-identical: integer addition is
 associative, so any accumulation order gives the same int32 sums) — because
 interpret-mode Pallas re-enters the grid per block at HLO level, which is
@@ -119,7 +120,7 @@ def lut_matmul_pallas(a_idx: jnp.ndarray, w_idx: jnp.ndarray,
     kwargs = {}
     if not interpret:       # TPU: m,n parallel; K revisits the accumulator
         from jax.experimental.pallas import tpu as pltpu
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
         kernel,

@@ -1,9 +1,10 @@
 """Public ops for the kernels package: jit'd wrappers + gradients.
 
-The matmul ops are *routed* per platform (DESIGN.md §12): on TPU they run
-the compiled tiled Pallas kernels with block sizes from the autotune cache
-(``kernels.autotune``); everywhere else they take the semantically
-identical XLA fallbacks (``*_xla``) — interpret-mode Pallas re-enters the
+The matmul ops are *routed* per platform (DESIGN.md §12): on TPU the
+codebook contraction runs the compiled tiled Pallas kernel with block
+sizes from the autotune cache (``kernels.autotune``); the lut contraction
+(which has no Mosaic lowering) and every op off-TPU take the semantically
+identical XLA routes (``*_xla``) — interpret-mode Pallas re-enters the
 grid per block at HLO level and is orders of magnitude slower, so it is a
 *testing* vehicle (tests/test_kernels.py runs it for block-walk parity),
 never a serving path.
@@ -13,9 +14,9 @@ never a serving path.
   integer indices.  This is exactly the gradient structure the paper's
   training uses between clustering events (weights move freely in float;
   here the codebook is the float degree of freedom).
-* ``lut_matmul(a_idx, w_idx, table)`` — integer-only, no gradient; the
-  Pallas and XLA routes produce bit-identical int32 accumulators (integer
-  addition is associative), so routing never shows up in goldens.
+* ``lut_matmul(a_idx, w_idx, table)`` — integer-only, no gradient; always
+  the XLA route (the Pallas kernel has no Mosaic lowering), whose int32
+  accumulators are bit-identical to the kernel's in interpret mode.
 * ``act_quant(x, kind, levels)`` — paper §2.1 backward: derivative of the
   *underlying* function, ignoring quantization.
 * ``kmeans_assign(values, centers)`` — no gradient (clustering is a
@@ -127,21 +128,17 @@ codebook_matmul.defvjp(_cm_fwd, _cm_bwd)
 def lut_matmul(a_idx, w_idx, table):
     """Integer accumulators of the §4 engine (no gradient, by construction).
 
-    Pallas (TPU) and XLA (elsewhere) routes are bit-identical — integer
-    addition is associative, so accumulation order cannot matter.
+    The XLA gather-accumulate on every platform: the Pallas kernel
+    (``lut_matmul_pallas``, the interpret-mode artifact) has no Mosaic
+    lowering, so autotune only picks the chunking/variant here.  Any
+    route is bit-identical — integer addition is associative.
     """
     m, k = a_idx.shape
     n = w_idx.shape[1]
     cfg = _tuned("lut", m, k, n, a_idx.dtype, table.shape)
-    if cfg.get("impl") == "xla":
-        _count_route("lut", "xla")
-        return _lm.lut_matmul_xla(a_idx, w_idx, table, kc=cfg["kc"],
-                                  variant=cfg["variant"])
-    _count_route("lut", "pallas_interpret" if _interp() else "pallas")
-    return _lm.lut_matmul_pallas(a_idx, w_idx, table, bm=cfg["bm"],
-                                 bn=cfg["bn"], bk=cfg["bk"],
-                                 unroll=cfg.get("unroll", 8),
-                                 interpret=_interp())
+    _count_route("lut", "xla")
+    return _lm.lut_matmul_xla(a_idx, w_idx, table, kc=cfg["kc"],
+                              variant=cfg["variant"])
 
 
 # --- paged KV cache: page-table gather ---------------------------------------

@@ -18,8 +18,14 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests)."""
+    """A (data, model) mesh over the first data·model local devices.
+
+    Raises when fewer devices exist: a mesh that silently shrank would
+    run (and report) a different parallel layout than the one asked for.
+    """
     n = len(jax.devices())
     if data * model > n:
-        data, model = n, 1
-    return make_mesh((data, model), ("data", "model"))
+        raise ValueError(f"a ({data}, {model}) mesh needs {data * model} "
+                         f"devices; found {n} ({jax.default_backend()})")
+    return make_mesh((data, model), ("data", "model"),
+                     devices=jax.devices()[:data * model])

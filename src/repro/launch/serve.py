@@ -122,6 +122,7 @@ import numpy as np
 
 import repro.configs as configs
 from repro.core.quantizer import cluster_params, init_state
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model_zoo import build
 from repro.serving import ServeEngine, SpecConfig, to_codebook_params
 from repro.core.export import kv_cache_bytes, memory_report
@@ -142,6 +143,39 @@ def _ensure_devices(n: int):
         os.execve(sys.executable, [sys.executable] + sys.argv, env)
     raise SystemExit(f"--tp {n} needs {n} devices; found "
                      f"{len(jax.devices())} ({jax.default_backend()})")
+
+
+def make_params(model, *, seed: int = 0, n_weights: int = 0):
+    """Random weights from ``model.init(PRNGKey(seed))`` — no checkpoint.
+
+    With ``n_weights`` they are clustered to |W| = n_weights unique values
+    (paper §2.2, key ``seed + 1``) and converted to codebook-index form
+    (§4, ``serving.to_codebook_params``).  Each stage's input is dropped
+    as soon as its output exists: at full width the float weights, their
+    clustered copy and the index planes would not all fit one chip.
+    """
+    params = model.init(jax.random.PRNGKey(seed))
+    if n_weights:
+        wq = model.cfg.quantized(n_weights=n_weights).wq
+        params, qstate = cluster_params(params, wq, init_state(wq),
+                                        wq.interval,
+                                        jax.random.PRNGKey(seed + 1))
+        params = to_codebook_params(params, wq, qstate)
+    return params
+
+
+def drain(engine, trace, *, quantum: int = 1, preempt: bool = True,
+          telemetry=None):
+    """Replay ``trace`` through one ``Server`` (the scheduler's step API)
+    until it drains.  Returns (report, wall seconds, server); the wall
+    clock is read here, never under ``serving/``."""
+    from repro.serving.server import Server
+
+    srv = Server(engine, quantum=quantum, preempt=preempt,
+                 telemetry=telemetry)
+    t0 = time.time()
+    rep = srv.replay(trace)
+    return rep, time.time() - t0, srv
 
 
 def report_numerics(engine, out_path=""):
@@ -176,7 +210,7 @@ def run_server(args, engine, cfg, mk_engine):
     """--server mode: drain a traffic trace through the scheduler (one
     Server, or a FleetRouter over --replicas of them) and report.  The
     ONLY wall-clock reads live here, outside serving/."""
-    from repro.serving.server import Server, load_trace, poisson_trace
+    from repro.serving.server import load_trace, poisson_trace
 
     if args.traffic == "replay":
         if not args.trace_file:
@@ -217,11 +251,8 @@ def run_server(args, engine, cfg, mk_engine):
                            scale_at=scale)
         wall = time.time() - t0
     else:
-        srv = Server(engine, quantum=args.quantum, preempt=args.preempt,
-                     telemetry=tel)
-        t0 = time.time()
-        rep = srv.replay(trace)
-        wall = time.time() - t0
+        rep, wall, srv = drain(engine, trace, quantum=args.quantum,
+                               preempt=args.preempt, telemetry=tel)
     print(f"[server] {rep.n_requests} requests / {rep.n_tokens} tokens "
           f"drained in {wall:.2f}s wall ({rep.n_tokens / wall:.1f} tok/s), "
           f"virtual makespan {rep.makespan:.3f}s")
@@ -376,6 +407,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0,
                     help="traffic/workload PRNG seed")
     args = ap.parse_args()
+    use_compile_cache()
     if args.paged and args.uniform:
         ap.error("--paged serves through the slot pool; drop --uniform")
     if args.spec_draft != "none" and args.uniform:
@@ -423,15 +455,13 @@ def main():
     if args.reduced:
         cfg = cfg.reduced()
     model = build(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    if args.backend != "dense" and not args.compress:
+        ap.error(f"--backend {args.backend} needs --compress (index-form "
+                 "weights)")
+    params = make_params(model, n_weights=args.n_weights if args.compress
+                         else 0)
 
     if args.compress:
-        wq = cfg.quantized(n_weights=args.n_weights).wq
-        params, qstate = cluster_params(params, wq, init_state(wq), wq.interval,
-                                        jax.random.PRNGKey(1))
-        cparams = to_codebook_params(params, wq, qstate)
-        from repro.core.quantizer import codebook_indices
-        idx_tree, _ = codebook_indices(params, wq, qstate)
         # end-to-end claim: weights AND serving state (dense float slab vs
         # the paged int8 cache sized for the actual tokens in flight)
         max_len = args.prompt_len + args.max_new + 8
@@ -444,13 +474,11 @@ def main():
             cfg.n_layers, cfg.n_kv, cfg.hd,
             args.prompt_len + args.max_new,
             quant=True, page_size=args.page_size)
-        rep = memory_report(idx_tree, wq.num_weights, max(cfg.act_levels, 32),
+        # the index planes of the served params (host copies; tensors
+        # below to_codebook_params' min_size stay float and are not counted)
+        rep = memory_report(params, args.n_weights, max(cfg.act_levels, 32),
                             kv_fp_bytes=kv_fp, kv_packed_bytes=kv_packed)
         print("[memory]", rep.row())
-        params = cparams
-    elif args.backend != "dense":
-        ap.error(f"--backend {args.backend} needs --compress (index-form "
-                 "weights)")
 
     spec = None
     if args.spec_draft != "none":

@@ -26,7 +26,7 @@ from repro.core.quantizer import (QuantizerState, cluster_params, init_state)
 from repro.data.synthetic import TokenPipeline
 from repro.distributed.fault_tolerance import FailureInjector, StragglerMonitor
 from repro.launch import steps as ST
-from repro.launch.mesh import make_local_mesh
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model_zoo import build
 from repro.optim import OptConfig, init_opt_state, warmup_cosine
 
@@ -143,6 +143,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:

@@ -55,8 +55,7 @@ def to_codebook_params(params, cfg: WeightQuantConfig, state: QuantizerState,
         book = state.codebooks.get("" if cfg.scope == "global" else path)
         if book is None:
             return None
-        idx = clustering.assign_to_centers(
-            leaf.astype(jnp.float32).reshape(-1), book).reshape(leaf.shape)
+        idx = clustering.assign_to_centers(leaf, book)
         book = jnp.asarray(book)
         if path_parts[0] in stacked_prefixes:
             book = jnp.broadcast_to(book[None], (leaf.shape[0],) + book.shape)

@@ -1316,6 +1316,26 @@ class ServeEngine:
 
     # --- public API ----------------------------------------------------------
 
+    def prefill_logits(self, prompt) -> jnp.ndarray:
+        """(vocab,) logits at the last position of ``prompt`` — what the
+        first generated token is sampled from — through the prefill program
+        ``serve()`` runs: paged engines stream the prompt through the
+        chunked prefill into freshly admitted pool pages, which are
+        released again; contiguous engines run the bucketed prefill."""
+        if self.paged:
+            pool = self.pool
+            adm = pool.admit(list(prompt), 1)
+            if adm is None:
+                raise RuntimeError("the page pool cannot admit the prompt")
+            try:
+                logits = self._chunked_prefill(pool, list(prompt), adm)
+            finally:
+                pool.retire(adm)
+        else:
+            toks, lens = self._pad_prompts([list(prompt)])
+            logits, _ = self._prefill(self.params, toks, lens)
+        return logits[0, -1, :self.model.cfg.vocab]
+
     def generate(self, prompts: list[list[int]], max_new: int = 32,
                  key=None) -> list[list[int]]:
         """Greedy (or sampled) continuation for a fixed batch of prompts.

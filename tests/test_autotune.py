@@ -82,7 +82,8 @@ def test_cold_vs_warm_identical_configs(tmp_path):
 
 def test_configs_are_valid_candidates():
     """Every resolved config is drawn from the candidate space: xla picks
-    carry impl='xla' (+ variant/kc for lut), tpu picks are Pallas tiles
+    and every lut pick carry impl='xla' + variant/kc (the lut Pallas
+    kernel has no Mosaic lowering), tpu codebook picks are Pallas tiles
     respecting lane/sublane quanta and the VMEM budget."""
     for s in SHAPES:
         cfg = autotune.kernel_config(
@@ -92,11 +93,12 @@ def test_configs_are_valid_candidates():
             s["kernel"], s["plat"], s["m"], s["k"], s["n"], s["dtype"],
             s["table_shape"])
         assert cfg in cands, (s, cfg)
-        if s["plat"] == "xla":
+        if s["kernel"] == "lut":
             assert cfg["impl"] == "xla"
-            if s["kernel"] == "lut":
-                assert cfg["variant"] in ("rows", "flat")
-                assert cfg["kc"] in (32, 64, 128)
+            assert cfg["variant"] in ("rows", "flat")
+            assert cfg["kc"] in (32, 64, 128)
+        elif s["plat"] == "xla":
+            assert cfg["impl"] == "xla"
         else:
             assert cfg["impl"] == "pallas"
             assert cfg["bm"] % 8 == 0 and cfg["bn"] % 128 == 0 \
@@ -118,6 +120,16 @@ def test_committed_cache_is_canonical_and_fresh():
     # canonical dump round-trips byte-identically
     blob = json.dumps(committed, sort_keys=True, indent=1) + "\n"
     assert blob == open(path).read()
+
+
+def test_lut_tpu_route_is_explicit_xla():
+    """On a TPU the lut engine resolves to its XLA route at every table
+    size, the paper's 4096 × 1000 working point included (a table no
+    Pallas tile budget could hold), instead of failing the selection."""
+    for table in ((4096, 1000), (512, 1000), (4096, 256)):
+        cfg = autotune.kernel_config("lut", 8, 2048, 6144, dtype="int32",
+                                     plat="tpu", table_shape=table)
+        assert cfg["impl"] == "xla", (table, cfg)
 
 
 def test_explicit_cache_dict_short_circuits():

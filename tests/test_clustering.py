@@ -33,14 +33,19 @@ def test_laplacian_spacing_widens():
     assert np.all(np.diff(d) > -1e-12)
 
 
-def test_assign_matches_bruteforce():
+@pytest.mark.parametrize("n", [500, CL._CHUNK + 4321])   # + chunked path
+def test_assign_matches_bruteforce(n):
     rng = np.random.default_rng(1)
     centers = jnp.sort(jnp.asarray(rng.normal(size=37)))
-    v = jnp.asarray(rng.normal(size=500) * 2)
+    v = jnp.asarray(rng.normal(size=n) * 2)
     idx = np.asarray(CL.assign_to_centers(v, centers))
-    brute = np.argmin(np.abs(np.asarray(v)[:, None]
-                             - np.asarray(centers)[None, :]), axis=1)
+    vn, cn = np.asarray(v), np.asarray(centers)
+    brute = np.concatenate([
+        np.argmin(np.abs(vn[i:i + 500_000, None] - cn[None, :]), axis=1)
+        for i in range(0, n, 500_000)])
     np.testing.assert_array_equal(idx, brute)
+    np.testing.assert_array_equal(np.asarray(CL.quantize_to_centers(v, centers)),
+                                  cn[brute])
 
 
 def test_kmeans_beats_uniform_on_laplacian():

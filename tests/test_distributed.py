@@ -175,3 +175,15 @@ def test_shardmap_flash_decode_matches_local():
     out = subprocess.run([sys.executable, "-c", _DECODE_MESH], env=env,
                          capture_output=True, text=True, timeout=900)
     assert "DECODE_MESH_OK" in out.stdout, out.stdout + out.stderr
+
+
+def test_local_mesh_refuses_to_shrink():
+    """A mesh asking for more devices than exist raises instead of
+    silently becoming a smaller layout than the one requested."""
+    import jax
+    from repro.launch.mesh import make_local_mesh
+
+    n = len(jax.devices())
+    assert make_local_mesh(1, n).shape["model"] == n
+    with pytest.raises(ValueError, match="devices"):
+        make_local_mesh(1, n + 1)

@@ -138,3 +138,20 @@ def test_int8_kv_cache_serving(tiny):
     got = eng.serve(PROMPTS[:3], max_new=4)
     assert [len(o) for o in got] == [len(p) + 4 for p in PROMPTS[:3]]
     assert all(0 <= t < cfg.vocab for o in got for t in o)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_prefill_logits_pick_the_first_served_token(tiny, paged):
+    """prefill_logits is the distribution serve() samples its first token
+    from: its argmax is the greedy first token, on both cache layouts, and
+    a paged call hands its pages back to the pool."""
+    cfg, model, params = tiny
+    eng = ServeEngine(model, params, max_len=64, max_batch=2, paged=paged,
+                      page_size=8)
+    for p in PROMPTS:
+        logits = eng.prefill_logits(p)
+        assert logits.shape == (cfg.vocab,)
+        assert bool(jnp.all(jnp.isfinite(logits)))
+        assert int(jnp.argmax(logits)) == eng.serve([p], max_new=1)[0][-1]
+    if paged:
+        assert eng.pool.free_claimable() == eng.pool.usable_pages
