@@ -10,9 +10,11 @@ paged int8 KV cache.
 Phases, in order; the first that raises ends the run:
 
 1. ``kernels``  — the routed codebook contraction at the QKV/O and FFN
-   sites (M = 8 and 128, the real layer-0 index planes) against
-   ``kernels/ref.py``, and the page gather on int8 K/V and f32 scale
-   pages against ``jnp.take``.
+   sites (M = 8 and 128, the real layer-0 index planes) and the routed
+   lut contraction at the FFN sites (M = 2 and 16, exact) against
+   ``kernels/ref.py``, the dequantize of those planes against
+   ``codebook[w_idx]`` (exact), and the page gather on int8 K/V and f32
+   scale pages against ``jnp.take``.
 2. ``dense``    — ``ServeEngine.serve``, backend ``dense``, on the
    index-form params.
 3. ``codebook`` — the same through the Pallas codebook kernel.
@@ -26,10 +28,12 @@ codebook backend at tp=4 over four chips against tp=1 on the first chip.
 
 Compile seconds are JAX's lowering and XLA-compile events of the phase.
 Tok/s lines are host-clock timings of one call for a handful of requests
-less those seconds (tracing stays in) — smoke timings, not benchmarks.  Greedy-token agreement with ``dense`` is
-reported, not asserted: on random weights a rounding difference flips
+less those seconds (tracing stays in) — smoke timings, not benchmarks.
+Greedy-token agreement with ``dense`` is reported, not asserted: on random weights a rounding difference flips
 argmax ties.  The last stdout line is ``{"ok": true, "device": ...}``,
 printed only when every phase passed; without a TPU the script fails.
+Each phase's start goes to stderr with its second, and every thread's
+stack is dumped there if the run is still going at ``WATCHDOG_S``.
 
     python chip_smoke.py [--seed N] [--four-chips]
 """
@@ -37,6 +41,7 @@ printed only when every phase passed; without a TPU the script fails.
 from __future__ import annotations
 
 import argparse
+import faulthandler
 import json
 import pathlib
 import sys
@@ -57,12 +62,19 @@ N_REQUESTS = 4
 # systematically wrong dequantize; single-entry exactness is probed
 # separately with one-hot rows, which must match bit for bit.
 KERNEL_RTOL = 2.0 ** -8
+LUT_K = 500               # K of the lut parity slice: ragged against 64
 # tp=4 vs tp=1 first-step logits: the row-parallel psum and the sharded
 # attention reassociate f32 sums; where that moves a bf16 activation by an
 # ulp, the int8 KV quantizer and 28 layers carry the difference on.  The
 # bound is relative to the logits' RMS; the measured drift prints beside
 # it.
 TP_LOGIT_RTOL = 0.05
+# Every thread's stack goes to stderr if the run is still going after
+# this many seconds (it keeps running): a run cut at a 1200 s limit then
+# shows where its time went.
+WATCHDOG_S = 1080
+
+_T0 = time.perf_counter()
 
 
 # lowering to MLIR and the XLA compile; tracing is left out because a
@@ -100,6 +112,13 @@ def _say(phase: str, msg: str) -> None:
     print(f"[{phase}] {msg}", flush=True)
 
 
+def _start(phase: str) -> None:
+    """Progress on stderr, so that the end of a cut run's stderr says
+    which phase it was in."""
+    print(f"[chip_smoke] {phase} starts at {time.perf_counter() - _T0:.1f}s",
+          file=sys.stderr, flush=True)
+
+
 def _peak_bytes(device) -> str:
     stats = device.memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
@@ -122,9 +141,10 @@ def kernel_phase(cfg, params, seed: int, device) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.kernels import ops, ref
+    from repro.kernels import dispatch, ops, ref
 
     phase = "kernels"
+    _start(phase)
     ops.reset_route_counts()
     blocks = params["blocks"]
     sites = {"wq": blocks["attn"]["wq"], "wk": blocks["attn"]["wk"],
@@ -132,6 +152,7 @@ def kernel_phase(cfg, params, seed: int, device) -> None:
              "w2": blocks["mlp"]["w2"]}
     matmul = jax.jit(ops.codebook_matmul)
     oracle = jax.jit(ref.codebook_matmul_ref)
+    dequantize = jax.jit(ops.dequantize, static_argnums=2)
     rng = np.random.default_rng(seed)
     for site, p in sites.items():
         idx, book = p["w_idx"][0], p["codebook"][0]      # layer 0
@@ -140,8 +161,13 @@ def kernel_phase(cfg, params, seed: int, device) -> None:
             x = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)
             got = np.asarray(matmul(x, idx, book))
             want = np.asarray(oracle(x, idx, book))
-            w = np.asarray(book[idx.astype(jnp.int32)].astype(jnp.bfloat16),
-                           np.float32)
+            w32 = book[idx.astype(jnp.int32)]
+            if not np.array_equal(np.asarray(dequantize(idx, book,
+                                                        jnp.float32)),
+                                  np.asarray(w32)):
+                raise AssertionError(f"dequantize {site} differs from "
+                                     "codebook[w_idx]")
+            w = np.asarray(w32.astype(jnp.bfloat16), np.float32)
             mag = np.abs(np.asarray(x, np.float32)) @ np.abs(w)
             err = np.abs(got - want)
             worst = float(np.max(err / np.maximum(mag, 1e-30)))
@@ -165,6 +191,28 @@ def kernel_phase(cfg, params, seed: int, device) -> None:
                  f"max |err|/Σ|xw| {worst:.3g} (limit {KERNEL_RTOL:.3g}), "
                  "one-hot rows exact")
 
+    # the lut route against the oracle with the engine's own table for the
+    # layer-0 codebook at the 4096-level grid: integer sums, so exact.  On
+    # a ragged K slice — the oracle gathers one table entry per MAC
+    lut = jax.jit(ops.lut_matmul)
+    lut_oracle = jax.jit(ref.lut_matmul_ref)
+    for site in ("w1", "w2"):
+        idx, book = sites[site]["w_idx"][0], sites[site]["codebook"][0]
+        k, n = idx.shape
+        spec = dispatch.make_lut_spec(np.asarray(book), k)
+        table = dispatch.build_lut_table(book, spec)
+        kk = min(LUT_K, k)
+        for m in (2, 16):
+            a = jnp.asarray(rng.integers(0, spec.levels, (m, kk)), jnp.int32)
+            w = idx[:kk]
+            if not np.array_equal(
+                    np.asarray(lut(a, w, table)),
+                    np.asarray(lut_oracle(a, w.astype(jnp.int32), table))):
+                raise AssertionError(f"lut {site} M={m} differs from the "
+                                     "oracle")
+            _say(phase, f"lut {site} M={m} ({kk}->{n}, table "
+                 f"{tuple(table.shape)}) exact")
+
     n_pages = 64
     table = jnp.asarray(rng.integers(0, n_pages, (4, 8)), jnp.int32)
     pools = {
@@ -181,12 +229,12 @@ def kernel_phase(cfg, params, seed: int, device) -> None:
         if not np.array_equal(got, want):
             raise AssertionError(f"gather_pages on {name} is not exact")
         _say(phase, f"gather_pages on {name} {tuple(pool.shape)} exact")
-    _check_routes(phase, "codebook")
+    _check_routes(phase, "codebook", "lut", "dequantize")
     _say(phase, f"peak_bytes_in_use {_peak_bytes(device)}")
 
 
-def _check_routes(phase: str, kernel: str) -> dict:
-    """Print the trace-time kernel routes; on a chip the kernel must have
+def _check_routes(phase: str, *kernels: str) -> dict:
+    """Print the trace-time kernel routes; on a chip the kernels must have
     run compiled, and nothing may have run in the Pallas interpreter."""
     from repro.kernels import ops
 
@@ -195,10 +243,11 @@ def _check_routes(phase: str, kernel: str) -> dict:
     interp = {k: v for k, v in routes.items() if k.endswith("_interpret")}
     if interp:
         raise AssertionError(f"kernels ran in interpret mode: {interp}")
-    if ops.supports_compiled_pallas() and kernel == "codebook" \
-            and not routes.get("codebook.pallas"):
-        raise AssertionError("no codebook contraction took the Pallas "
-                             "kernel")
+    for kernel in kernels:
+        if ops.supports_compiled_pallas() and kernel != "dense" \
+                and not routes.get(f"{kernel}.pallas"):
+            raise AssertionError(f"no {kernel} contraction took the Pallas "
+                                 "kernel")
     return routes
 
 
@@ -212,6 +261,7 @@ def serve_phase(phase: str, model, params, backend: str, prompts,
     from repro.kernels import ops
     from repro.serving import ServeEngine
 
+    _start(phase)
     ops.reset_route_counts()
     engine = ServeEngine(model, params, max_len=PROMPT_LEN + MAX_NEW + 8,
                          backend=backend, max_batch=len(prompts), paged=True,
@@ -236,7 +286,7 @@ def serve_phase(phase: str, model, params, backend: str, prompts,
          f"{n_tok / max(wall - comp, 1e-9):.3f} tok/s host clock, less "
          "compile seconds")
     _say(phase, f"peak_bytes_in_use {_peak_bytes(device)}")
-    _check_routes(phase, backend)
+    _check_routes(phase, backend, "dequantize")
     if ref is not None:
         _say(phase, f"greedy agreement with dense: "
              f"{_agreement(gen, [r[:max_new] for r in ref])}")
@@ -249,6 +299,7 @@ def server_phase(engine, cfg, seed: int, device, clock) -> None:
     from repro.serving.server import poisson_trace
 
     phase = "server"
+    _start(phase)
     ops.reset_route_counts()
     trace = poisson_trace(seed, 6, rate=40.0, vocab=cfg.vocab,
                           plen=(2, PROMPT_LEN), max_new=(2, MAX_NEW),
@@ -267,7 +318,7 @@ def server_phase(engine, cfg, seed: int, device, clock) -> None:
          f"{rep.n_tokens / max(wall - comp, 1e-9):.3f} tok/s host clock, "
          "less compile seconds")
     _say(phase, f"peak_bytes_in_use {_peak_bytes(device)}")
-    _check_routes(phase, "codebook")
+    _check_routes(phase, "codebook", "dequantize")
 
 
 def four_chip_phase(model, params, prompts, devices, clock) -> None:
@@ -279,6 +330,7 @@ def four_chip_phase(model, params, prompts, devices, clock) -> None:
     from repro.serving import ServeEngine
 
     phase = "tp4"
+    _start(phase)
     kw = dict(max_len=PROMPT_LEN + MAX_NEW + 8, backend="codebook",
               max_batch=len(prompts), paged=True, page_size=PAGE,
               kv_dtype="int8")
@@ -329,6 +381,7 @@ def run(cfg, *, seed: int = 0, four_chips: bool = False):
     clock = CompileSeconds()
     jax.monitoring.register_event_duration_secs_listener(clock)
     try:
+        _start("setup")
         model = build(cfg)
         params, wall, _ = _timed(clock, lambda: jax.block_until_ready(
             make_params(model, seed=seed, n_weights=N_WEIGHTS)))
@@ -351,8 +404,7 @@ def run(cfg, *, seed: int = 0, four_chips: bool = False):
                                 ref=dense)
         server_phase(engine, cfg, seed, devices[0], clock)
         del engine
-        # last: the XLA gather route is the slowest and its chip cost the
-        # least known
+        # last: the slowest engine, and its chip cost the least known
         serve_phase("lut", model, params, "lut", prompts[:2], 4, devices[0],
                     clock, ref=dense[:2])
     finally:
@@ -384,7 +436,9 @@ def main() -> None:
     want = 4 if args.four_chips else 1
     if len(devices) < want:
         _fail(f"needs {want} TPU chips; found {len(devices)}")
+    faulthandler.dump_traceback_later(WATCHDOG_S)
     run(configs.get(ARCH), seed=args.seed, four_chips=args.four_chips)
+    faulthandler.cancel_dump_traceback_later()
     d = devices[0]
     print(json.dumps({"ok": True, "device": {
         "platform": d.platform, "kind": d.device_kind,

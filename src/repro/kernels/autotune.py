@@ -2,10 +2,9 @@
 
 Every routed contraction (``ops.codebook_matmul`` / ``ops.lut_matmul``)
 asks this module for its launch config at trace time — block sizes for
-the compiled Pallas codebook kernel on TPU, chunking/variant for the XLA
-routes (every lut contraction, and codebook off-TPU).  Shapes are static
-under jit, so the lookup happens once per traced shape and folds into the
-executable.
+the compiled Pallas kernels on TPU, chunking/variant for the XLA routes
+off-TPU.  Shapes are static under jit, so the lookup happens once per
+traced shape and folds into the executable.
 
 Selection is a *deterministic cost model* over the candidate space —
 padded-tile memory traffic in integer bytes, largest-tile-first
@@ -95,18 +94,33 @@ def candidates(kernel: str, plat: str, m: int, k: int, n: int,
          multiples of 128, bm multiples of the f32 sublane (8); everything
          that must be VMEM-co-resident (double-buffered streams, the
          accumulator, the in-kernel dequantize temporaries, the codebook)
-         has to fit the budget.  lut — the XLA candidates below: the
-         Pallas lut kernel gathers from the whole flattened table, and
-         Mosaic lowers a gather only within one 128-lane vreg, so the
-         compiled route on a TPU is the XLA gather-accumulate.
+         has to fit the budget.  lut — (bm, bn, bk) tiles of the row-gather
+         kernel: bm is M up to 8 rows (its lookups unroll per row), bn a
+         lane multiple up to 512, bk a multiple of 32 (the int8 id tile)
+         up to 64, with the double-buffered (bm, bk, S·128) row block in
+         the budget.
     xla: lut — gather variant ('rows' | 'flat') × K-chunk size; codebook —
          a single fused gather+dot, nothing to tune.
     """
-    if kernel == "lut":
+    if kernel == "lut" and plat == "xla":
         return [{"impl": "xla", "variant": v, "kc": kc}
                 for v in ("rows", "flat") for kc in (32, 64, 128)]
     if plat == "xla":
         return [{"impl": "xla"}]
+    if kernel == "lut":
+        row_bytes = 4 * _ceil_to(int(table_shape[-1]), _LANE)
+        bm = min(m, _SUBLANE)
+        out = []
+        for bn in _tile_sizes(n, _LANE, 512):
+            for bk in _tile_sizes(k, 32, 64):
+                # 2× the streamed row block and id tile; 4 int32 (bk, bn)
+                # planes: ids, segments, lanes, looked-up values
+                vmem = (2 * (bm * bk * row_bytes + bk * bn * 4)
+                        + bm * bn * 4 + 4 * bk * bn * 4)
+                if vmem <= _VMEM_BUDGET:
+                    out.append({"impl": "pallas", "bm": bm, "bn": bn,
+                                "bk": bk})
+        return out
 
     book_bytes = 4 * _ceil_to(int(table_shape[-1]), _LANE)
     in_bytes = 2 if dtype == "bfloat16" else 4
@@ -158,6 +172,11 @@ def model_cost(kernel: str, cfg: dict, m: int, k: int, n: int,
         return gather + steps * 50_000 + spill
     bm, bn, bk = int(cfg["bm"]), int(cfg["bn"]), int(cfg["bk"])
     gm, gn, gk = -(-m // bm), -(-n // bn), -(-k // bk)
+    if kernel == "lut":
+        # the (bm, bk, S·128) int32 row block streams once per N tile
+        row_bytes = 4 * _ceil_to(int(table_shape[-1]), _LANE)
+        return (gm * gn * gk * (bm * bk * row_bytes + bk * bn * 4)
+                + gm * gn * bm * bn * 4)
     in_bytes = 2 if dtype == "bfloat16" else 4
     stream = gm * gn * gk * (bm * bk + bk * bn) * in_bytes
     out_pass = gm * gn * bm * bn * 4
@@ -179,9 +198,15 @@ def _measure(kernel: str, cfg: dict, m: int, k: int, n: int,
         a = jnp.asarray(rng.integers(0, r, (m, k)), jnp.int32)
         w = jnp.asarray(rng.integers(0, c, (k, n)), jnp.int32)
         t = jnp.asarray(rng.integers(-1000, 1000, (r, c)), jnp.int32)
-        from repro.kernels.lut_matmul import lut_matmul_xla
-        fn = lambda: lut_matmul_xla(a, w, t, kc=cfg["kc"],       # noqa: E731
-                                    variant=cfg["variant"])
+        from repro.kernels.lut_matmul import (lut_matmul_pallas,
+                                              lut_matmul_xla)
+        if cfg.get("impl") == "xla":
+            fn = lambda: lut_matmul_xla(                         # noqa: E731
+                a, w, t, kc=cfg["kc"], variant=cfg["variant"])
+        else:
+            fn = lambda: lut_matmul_pallas(                      # noqa: E731
+                a, w, t, bm=cfg["bm"], bn=cfg["bn"], bk=cfg["bk"],
+                interpret=False)
     else:
         w_ = int(table_shape[-1])
         x = jnp.asarray(rng.standard_normal((m, k)),

@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 __all__ = ["codebook_matmul_kernel", "codebook_matmul_pallas",
-           "codebook_matmul_xla"]
+           "codebook_matmul_xla", "dequantize_pallas"]
 
 
 def _canonical_idx(idx, n: int):
@@ -124,9 +124,7 @@ def codebook_matmul_pallas(x: jnp.ndarray, w_idx: jnp.ndarray,
     k2, n = w_idx.shape
     assert k == k2, (x.shape, w_idx.shape)
     n_book = codebook.shape[-1]
-    n_seg = pl.cdiv(n_book, _LANES)
-    book2d = jnp.pad(codebook.astype(jnp.float32).reshape(-1),
-                     (0, n_seg * _LANES - n_book)).reshape(n_seg, _LANES)
+    book2d = _book2d(codebook)
 
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk))
     kwargs = {}
@@ -141,7 +139,7 @@ def codebook_matmul_pallas(x: jnp.ndarray, w_idx: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((n_seg, _LANES), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec(book2d.shape, lambda i, j, kk: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
@@ -149,6 +147,44 @@ def codebook_matmul_pallas(x: jnp.ndarray, w_idx: jnp.ndarray,
         **kwargs,
     )(x, w_idx, book2d)
     return out
+
+
+def _book2d(codebook):
+    """The codebook as an (S, 128) f32 block, zero-padded (``_dequantize``)."""
+    n_book = codebook.shape[-1]
+    n_seg = pl.cdiv(n_book, _LANES)
+    return jnp.pad(codebook.astype(jnp.float32).reshape(-1),
+                   (0, n_seg * _LANES - n_book)).reshape(n_seg, _LANES)
+
+
+def _dequantize_kernel(idx_ref, book_ref, out_ref, *, n_book: int):
+    out_ref[...] = _dequantize(idx_ref[...].astype(jnp.int32), book_ref[...],
+                               n_book).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("dtype", "bk", "bn", "interpret"))
+def dequantize_pallas(w_idx: jnp.ndarray, codebook: jnp.ndarray, *,
+                      dtype=jnp.float32, bk: int = 256, bn: int = 512,
+                      interpret: bool = True) -> jnp.ndarray:
+    """``codebook[w_idx]`` as ``dtype`` — the weights themselves, for the
+    contractions that dequantize before an XLA dot (the ``dense`` backend,
+    the tied lm-head).  The same lane gathers as the matmul kernel: a TPU
+    runs an XLA gather from a |W|-entry table one element at a time, this
+    a vreg at a time.  Bit-identical to the XLA gather: the values are the
+    codebook's, cast once.  Edge blocks: ids clamped, stores masked.
+    """
+    k, n = w_idx.shape
+    book2d = _book2d(codebook)
+    return pl.pallas_call(
+        functools.partial(_dequantize_kernel, n_book=codebook.shape[-1]),
+        grid=(pl.cdiv(k, bk), pl.cdiv(n, bn)),
+        in_specs=[pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
+                  pl.BlockSpec(book2d.shape, lambda i, j: (0, 0))],
+        out_specs=pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((k, n), dtype),
+        interpret=interpret,
+    )(w_idx, book2d)
 
 
 @jax.jit

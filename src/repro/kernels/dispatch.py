@@ -51,7 +51,8 @@ import numpy as np
 from repro.kernels import probes
 
 __all__ = ["BACKENDS", "LutSpec", "BackendSpec", "make_lut_spec",
-           "use_backend", "matmul_backend", "matmul_mesh", "backend_matmul",
+           "use_backend", "matmul_backend", "matmul_mesh", "dequantize",
+           "backend_matmul",
            "bind_backend", "build_lut_table", "attach_lut_tables",
            "kernel_config", "autotune_shapes", "matmul_call_counts",
            "reset_matmul_call_counts"]
@@ -279,6 +280,19 @@ def use_backend(name: str, lut_spec: LutSpec | None = None, mesh=None):
 def matmul_mesh():
     """The mesh index-form contractions are being sharded over (or None)."""
     return _STATE.mesh
+
+
+def dequantize(w_idx, codebook, dtype):
+    """``codebook[w_idx]`` as ``dtype``: the weights of an index-form tensor
+    for the contractions that dequantize before an XLA dot (the ``dense``
+    backend, the tied lm-head).  ``ops.dequantize`` where one device holds
+    the operands; under a mesh XLA's gather, which the SPMD partitioner
+    splits (a Pallas call it would all-gather, see ``_sharded_matmul``)."""
+    from repro.kernels import ops  # lazy: keep pallas off the import path
+
+    if _STATE.mesh is None:
+        return ops.dequantize(w_idx, codebook, dtype)
+    return codebook[w_idx.astype(jnp.int32)].astype(dtype)
 
 
 def backend_matmul(x, w_idx, codebook, kind: str | None = None, table=None):

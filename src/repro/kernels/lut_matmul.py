@@ -2,40 +2,39 @@
 
 acc[m, n] = Σ_k  M[a_idx[m, k], w_idx[k, n]]
 
-Both operands are *indices*; the multiplication table M is VMEM-resident
-(flattened for a single-gather address computation ``a·C + w``).  The grid
-is ``(⌈M/bm⌉, ⌈N/bn⌉, ⌈K/bk⌉)`` with K innermost, so each (bm, bn) int32
-accumulator tile stays resident in VMEM across the whole K sweep and the
-table — whose BlockSpec index map is constant — is DMA'd exactly once and
-then revisited from fast memory by every grid step (Pallas only re-fetches
-a block when its index map moves; with the K dimension marked ``arbitrary``
-the other operand streams are double-buffered behind the gather work).
+Both operands are *indices* into the |A|×|W| multiplication table M.  A
+TPU kernel may gather only inside one 128-lane vreg, so the contraction
+is split in two gathers that each stay legal and cheap:
+
+* rows — XLA gathers the table row of every activation,
+  r[m, k] = M[a_idx[m, k]], lane-padded to S·128 entries (S = ⌈|W|/128⌉,
+  8 at the paper's |W| = 1000): one contiguous row per index, where an
+  element gather from the 16.4 MB paper table would touch HBM once per MAC;
+* lookups — the kernel splits every weight id into a segment ``w // 128``
+  and a lane ``w % 128``, runs S lane gathers (``take_along_axis``) over
+  the row's segments and keeps, by select, the one the id points into —
+  the codebook kernel's dequantize with a per-row source — then sums over
+  K into the int32 accumulator.
+
+The grid is ``(⌈M/bm⌉, ⌈N/bn⌉, ⌈K/bk⌉)`` with K innermost, so each
+(bm, bn) accumulator tile stays resident in VMEM across the K sweep while
+the (bm, bk, S·128) row blocks and (bk, bn) id tiles double-buffer behind
+the lookups.  The lookups unroll per row of the tile, which is why ``bm``
+stays at 8 or below.
 
 Ragged shapes are handled by *explicit masking*, not implicit padding:
+the K tail of an edge block (undefined rows and ids on TPU) is zeroed by a
+``k < K`` mask, every id is clamped into the table before its lookup, and
+the out-of-range rows/columns of M/N edge tiles are dropped by Pallas'
+masked edge-block stores.
 
-* K tail: a per-element ``k < K`` mask zeroes the padded contributions
-  (the old wrapper padded with (row 0, col 0) pairs and subtracted
-  ``pad·table[0,0]`` afterwards — correct only while the pad indices were
-  actually zero-filled).
-* M/N edges: loads beyond the array edge are undefined on TPU, so every
-  gather address is clamped into the table before the lookup; the out-of-
-  range rows/columns of the output tile are dropped by Pallas' masked
-  edge-block stores.
-
-The K loop walks ``unroll`` steps per ``fori_loop`` iteration so the
-gathered intermediate is ``unroll`` (bm, bn) tiles rather than a
-(bm, bk, bn) cube — VMEM stays bounded by 3 tiles + the table.
-
-The kernel is the *faithful artifact* of the multiply-free dataflow, run
-in Pallas interpret mode; it has no Mosaic lowering — its gather into the
-flattened |A|·|W| table spans far more than the one 128-lane vreg a TPU
-kernel may gather within (DESIGN.md §12).  Every serving platform, the
-TPU included, takes ``lut_matmul_xla`` below — the same gather-accumulate
-contraction expressed as XLA ops (bit-identical: integer addition is
-associative, so any accumulation order gives the same int32 sums) — because
-interpret-mode Pallas re-enters the grid per block at HLO level, which is
-orders of magnitude slower than one fused XLA gather.  Parity between the
-two (and the jnp oracle in ``kernels.ref``) is exact and property-tested.
+On a TPU the serving route is this kernel, compiled; off-TPU it runs
+``lut_matmul_xla`` below — the same gather-accumulate as XLA ops —
+because interpret-mode Pallas re-enters the grid per block at HLO level,
+orders of magnitude slower than one fused XLA gather.  Both are
+bit-identical (integer addition is associative, so any accumulation order
+gives the same int32 sums); parity with each other and the jnp oracle in
+``kernels.ref`` is exact and property-tested.
 """
 
 from __future__ import annotations
@@ -48,6 +47,8 @@ from jax.experimental import pallas as pl
 
 __all__ = ["lut_matmul_kernel", "lut_matmul_pallas", "lut_matmul_xla"]
 
+_LANES = 128    # one vreg row: the widest gather Mosaic lowers
+
 
 def _canonical_idx(idx, n: int):
     """int32 ids in [0, n) — narrow dtypes store ids ≥ 2^(bits-1) as
@@ -56,86 +57,82 @@ def _canonical_idx(idx, n: int):
     return jnp.where(idx < 0, idx + n, idx)
 
 
-def lut_matmul_kernel(a_ref, w_ref, table_ref, out_ref, *,
-                      bk: int, k_total: int, unroll: int):
-    """One (bm, bn) int32 accumulator tile, revisited across the K grid."""
+def lut_matmul_kernel(r_ref, w_ref, out_ref, *, bk: int, k_total: int,
+                      n_cols: int):
+    """One (bm, bn) int32 accumulator tile, revisited across the K grid;
+    ``r_ref`` holds the block's table rows r[m, k] = table[a[m, k]]."""
     kg = pl.program_id(2)
 
     @pl.when(kg == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    flat = table_ref[0, :]                          # (R*C,) int32, resident
-    a_blk = a_ref[...]                              # (bm, bk) int32, pre-·C
-    w_blk = w_ref[...]                              # (bk, bn) int32
-    size = flat.shape[0]
-    base = kg * bk
+    w = jnp.clip(_canonical_idx(w_ref[...], n_cols), 0, n_cols - 1)
+    hi, lo = w // _LANES, w % _LANES
+    n_seg = r_ref.shape[-1] // _LANES
+    keep = None
+    if k_total % bk:
+        # the K tail of an edge block holds undefined rows and ids
+        keep = (jax.lax.broadcasted_iota(jnp.int32, w.shape, 0) + kg * bk
+                < k_total)
+    sums = []
+    for mi in range(r_ref.shape[0]):                    # static unroll
+        r = r_ref[mi]                                   # (bk, S·128)
+        cols = []
+        for c in range(0, w.shape[1], _LANES):
+            lo_c, hi_c = lo[:, c:c + _LANES], hi[:, c:c + _LANES]
+            g = jnp.zeros(lo_c.shape, jnp.int32)
+            for s in range(n_seg):
+                seg = r[:, s * _LANES:(s + 1) * _LANES]
+                g = jnp.where(hi_c == s,
+                              jnp.take_along_axis(seg, lo_c, axis=1), g)
+            cols.append(g)
+        g = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
+        if keep is not None:
+            g = jnp.where(keep, g, 0)
+        sums.append(jnp.sum(g, axis=0, keepdims=True))
+    out_ref[...] += (sums[0] if len(sums) == 1
+                     else jnp.concatenate(sums, axis=0))
 
-    def step(acc, kk):
-        # clamp: edge-block loads are undefined on TPU; any address they
-        # produce is pulled into the table, and the mask / masked store
-        # guarantees the value never lands in a live accumulator cell
-        addr = jnp.clip(a_blk[:, kk][:, None] + w_blk[kk, :][None, :],
-                        0, size - 1)                # (bm, bn)
-        g = jnp.take(flat, addr, axis=0, mode="clip")
-        return acc + jnp.where(base + kk < k_total, g, 0)
 
-    def body(i, acc):
-        for u in range(unroll):                     # trace-time unroll
-            acc = step(acc, i * unroll + u)
-        return acc
-
-    acc = jax.lax.fori_loop(0, bk // unroll, body, jnp.zeros_like(out_ref))
-    out_ref[...] += acc
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "unroll", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def lut_matmul_pallas(a_idx: jnp.ndarray, w_idx: jnp.ndarray,
-                      table: jnp.ndarray, *,
-                      bm: int = 128, bn: int = 128, bk: int = 128,
-                      unroll: int = 8, interpret: bool = True) -> jnp.ndarray:
-    """a_idx: (M, K) int rows of the table; w_idx: (K, N) int columns;
+                      table: jnp.ndarray, *, bm: int = 8, bn: int = 512,
+                      bk: int = 64, interpret: bool = True) -> jnp.ndarray:
+    """a_idx: (M, K) int table rows; w_idx: (K, N) int table columns;
     table: (R, C) int32.  Returns (M, N) int32 accumulators.
 
-    Dims need not be multiples of the block sizes — edge blocks are masked
-    inside the kernel (module docstring).  The row index is pre-multiplied
-    by C outside the kernel (one integer multiply per *index*, amortised —
-    the per-MAC path stays multiply-free; on-device this constant-stride
-    scaling is an address computation).
+    Dims need not be multiples of the block sizes (module docstring).
     """
     m, k = a_idx.shape
     k2, n = w_idx.shape
     assert k == k2, (a_idx.shape, w_idx.shape)
     rows, n_cols = table.shape
-    a_scaled = _canonical_idx(a_idx, rows) * n_cols
-    w_can = _canonical_idx(w_idx, n_cols)
-    flat = table.reshape(1, -1).astype(jnp.int32)
-    while bk % unroll:
-        unroll //= 2
+    n_seg = pl.cdiv(n_cols, _LANES)
+    padded = jnp.pad(table.astype(jnp.int32),
+                     ((0, 0), (0, n_seg * _LANES - n_cols)))
+    a = jnp.clip(_canonical_idx(a_idx, rows), 0, rows - 1)
+    r = jnp.take(padded, a, axis=0)                 # (M, K, S·128)
 
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk))
-    kernel = functools.partial(lut_matmul_kernel, bk=bk, k_total=k,
-                               unroll=max(unroll, 1))
     kwargs = {}
     if not interpret:       # TPU: m,n parallel; K revisits the accumulator
         from jax.experimental.pallas import tpu as pltpu
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-    out = pl.pallas_call(
-        kernel,
+    return pl.pallas_call(
+        functools.partial(lut_matmul_kernel, bk=bk, k_total=k, n_cols=n_cols),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bm, bk, n_seg * _LANES),
+                         lambda i, j, kk: (i, kk, 0)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, flat.shape[1]), lambda i, j, kk: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
         **kwargs,
-    )(a_scaled, w_can, flat)
-    return out
+    )(r, w_idx)
 
 
 @functools.partial(jax.jit, static_argnames=("kc", "variant"))

@@ -1,22 +1,23 @@
 """Public ops for the kernels package: jit'd wrappers + gradients.
 
 The matmul ops are *routed* per platform (DESIGN.md §12): on TPU the
-codebook contraction runs the compiled tiled Pallas kernel with block
-sizes from the autotune cache (``kernels.autotune``); the lut contraction
-(which has no Mosaic lowering) and every op off-TPU take the semantically
-identical XLA routes (``*_xla``) — interpret-mode Pallas re-enters the
-grid per block at HLO level and is orders of magnitude slower, so it is a
-*testing* vehicle (tests/test_kernels.py runs it for block-walk parity),
-never a serving path.
+codebook and lut contractions run compiled tiled Pallas kernels with block
+sizes from the autotune cache (``kernels.autotune``); every op off-TPU
+takes the semantically identical XLA route (``*_xla``) — interpret-mode
+Pallas re-enters the grid per block at HLO level and is orders of
+magnitude slower, so it is a *testing* vehicle (tests/test_kernels.py runs
+it for block-walk parity), never a serving path.
 
 * ``codebook_matmul(x, w_idx, codebook)`` — differentiable w.r.t. x and the
   codebook (d codebook = scatter-add of x^T·g over indices), NOT w.r.t. the
   integer indices.  This is exactly the gradient structure the paper's
   training uses between clustering events (weights move freely in float;
   here the codebook is the float degree of freedom).
-* ``lut_matmul(a_idx, w_idx, table)`` — integer-only, no gradient; always
-  the XLA route (the Pallas kernel has no Mosaic lowering), whose int32
-  accumulators are bit-identical to the kernel's in interpret mode.
+* ``lut_matmul(a_idx, w_idx, table)`` — integer-only, no gradient; the
+  row-gather Pallas kernel on TPU, the XLA gather-accumulate elsewhere —
+  bit-identical int32 accumulators.
+* ``dequantize(w_idx, codebook, dtype)`` — the weights of an index-form
+  tensor, for contractions that dequantize before an XLA dot; serving-only.
 * ``act_quant(x, kind, levels)`` — paper §2.1 backward: derivative of the
   *underlying* function, ignoring quantization.
 * ``kmeans_assign(values, centers)`` — no gradient (clustering is a
@@ -36,9 +37,9 @@ from repro.kernels import kmeans1d as _km
 from repro.kernels import lut_matmul as _lm
 from repro.kernels import page_gather as _pg
 
-__all__ = ["codebook_matmul", "lut_matmul", "act_quant", "kmeans_assign",
-           "gather_pages", "on_tpu", "supports_compiled_pallas",
-           "route_counts", "reset_route_counts"]
+__all__ = ["codebook_matmul", "dequantize", "lut_matmul", "act_quant",
+           "kmeans_assign", "gather_pages", "on_tpu",
+           "supports_compiled_pallas", "route_counts", "reset_route_counts"]
 
 # Trace-time platform-route counters: "{kernel}.{impl}" -> times that impl
 # was selected while tracing (pallas | pallas_interpret | xla).  Read as
@@ -123,22 +124,40 @@ def _cm_bwd(res, g):
 codebook_matmul.defvjp(_cm_fwd, _cm_bwd)
 
 
+def dequantize(w_idx, codebook, dtype):
+    """``codebook[w_idx]`` as ``dtype`` — identical values on every route:
+    the Pallas dequantize on TPU (lane gathers, a vreg at a time), the XLA
+    gather elsewhere.  No gradient (serving-only)."""
+    if supports_compiled_pallas():
+        _count_route("dequantize", "pallas")
+        return _cm.dequantize_pallas(w_idx, codebook, dtype=dtype,
+                                     interpret=False)
+    _count_route("dequantize", "xla")
+    return codebook[w_idx.astype(jnp.int32)].astype(dtype)
+
+
 # --- faithful integer engine -------------------------------------------------
 
 def lut_matmul(a_idx, w_idx, table):
     """Integer accumulators of the §4 engine (no gradient, by construction).
 
-    The XLA gather-accumulate on every platform: the Pallas kernel
-    (``lut_matmul_pallas``, the interpret-mode artifact) has no Mosaic
-    lowering, so autotune only picks the chunking/variant here.  Any
-    route is bit-identical — integer addition is associative.
+    On TPU the Pallas kernel (``lut_matmul_pallas``: whole table rows
+    gathered per activation, column lookups as in-kernel lane gathers);
+    elsewhere the XLA gather-accumulate, whose chunking/variant
+    autotune picks.  Any route is bit-identical — integer addition is
+    associative.
     """
     m, k = a_idx.shape
     n = w_idx.shape[1]
     cfg = _tuned("lut", m, k, n, a_idx.dtype, table.shape)
-    _count_route("lut", "xla")
-    return _lm.lut_matmul_xla(a_idx, w_idx, table, kc=cfg["kc"],
-                              variant=cfg["variant"])
+    if cfg.get("impl") == "xla":
+        _count_route("lut", "xla")
+        return _lm.lut_matmul_xla(a_idx, w_idx, table, kc=cfg["kc"],
+                                  variant=cfg["variant"])
+    _count_route("lut", "pallas_interpret" if _interp() else "pallas")
+    return _lm.lut_matmul_pallas(a_idx, w_idx, table, bm=cfg["bm"],
+                                 bn=cfg["bn"], bk=cfg["bk"],
+                                 interpret=_interp())
 
 
 # --- paged KV cache: page-table gather ---------------------------------------

@@ -57,7 +57,7 @@ def dense(p, x, kind: str | None = None):
             if "b" in p:
                 y = y + p["b"].astype(x.dtype)
             return y
-        w = p["codebook"][p["w_idx"].astype(jnp.int32)].astype(x.dtype)
+        w = dispatch.dequantize(p["w_idx"], p["codebook"], x.dtype)
     else:
         w = p["w"].astype(x.dtype)
     y = x @ w

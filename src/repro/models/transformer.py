@@ -26,7 +26,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import probes
+from repro.kernels import dispatch, probes
 from repro.models import layers as L
 from repro.models import attention as A
 from repro.models import moe as M
@@ -306,7 +306,8 @@ def _scan(block_fn, x, stacked, cfg, with_cache=False, cache=None):
 
 def _logits(p, cfg, x):
     if cfg.tie_embeddings:
-        t = (p["embed"]["codebook"][p["embed"]["w_idx"].astype(jnp.int32)]
+        t = (dispatch.dequantize(p["embed"]["w_idx"], p["embed"]["codebook"],
+                                 p["embed"]["codebook"].dtype)
              if "w_idx" in p["embed"] else p["embed"]["table"])
         logits = jnp.dot(x, t.T, preferred_element_type=jnp.float32)
     else:

@@ -82,8 +82,7 @@ def test_cold_vs_warm_identical_configs(tmp_path):
 
 def test_configs_are_valid_candidates():
     """Every resolved config is drawn from the candidate space: xla picks
-    and every lut pick carry impl='xla' + variant/kc (the lut Pallas
-    kernel has no Mosaic lowering), tpu codebook picks are Pallas tiles
+    carry impl='xla' (+ variant/kc for lut), tpu picks are Pallas tiles
     respecting lane/sublane quanta and the VMEM budget."""
     for s in SHAPES:
         cfg = autotune.kernel_config(
@@ -93,12 +92,16 @@ def test_configs_are_valid_candidates():
             s["kernel"], s["plat"], s["m"], s["k"], s["n"], s["dtype"],
             s["table_shape"])
         assert cfg in cands, (s, cfg)
-        if s["kernel"] == "lut":
+        if s["plat"] == "xla" and s["kernel"] == "lut":
             assert cfg["impl"] == "xla"
             assert cfg["variant"] in ("rows", "flat")
             assert cfg["kc"] in (32, 64, 128)
         elif s["plat"] == "xla":
             assert cfg["impl"] == "xla"
+        elif s["kernel"] == "lut":
+            assert cfg["impl"] == "pallas"
+            assert cfg["bm"] == min(s["m"], 8) and cfg["bn"] % 128 == 0 \
+                and cfg["bk"] % 32 == 0
         else:
             assert cfg["impl"] == "pallas"
             assert cfg["bm"] % 8 == 0 and cfg["bn"] % 128 == 0 \
@@ -122,14 +125,16 @@ def test_committed_cache_is_canonical_and_fresh():
     assert blob == open(path).read()
 
 
-def test_lut_tpu_route_is_explicit_xla():
-    """On a TPU the lut engine resolves to its XLA route at every table
-    size, the paper's 4096 × 1000 working point included (a table no
-    Pallas tile budget could hold), instead of failing the selection."""
+@pytest.mark.parametrize("m", [2, 16])
+def test_lut_tpu_route_is_rows_kernel(m):
+    """On a TPU the lut engine resolves to the row-gather Pallas kernel at
+    every table size, the paper's 4096 × 1000 working point included: the
+    kernel streams table rows, so no tile has to hold the whole table."""
     for table in ((4096, 1000), (512, 1000), (4096, 256)):
-        cfg = autotune.kernel_config("lut", 8, 2048, 6144, dtype="int32",
+        cfg = autotune.kernel_config("lut", m, 2048, 6144, dtype="int32",
                                      plat="tpu", table_shape=table)
-        assert cfg["impl"] == "xla", (table, cfg)
+        assert cfg["impl"] == "pallas" and cfg["bm"] == min(m, 8), \
+            (table, cfg)
 
 
 def test_explicit_cache_dict_short_circuits():

@@ -33,8 +33,12 @@ def test_laplacian_spacing_widens():
     assert np.all(np.diff(d) > -1e-12)
 
 
+@pytest.mark.parametrize("method", ["scan", "compare_all"])  # CPU, TPU
 @pytest.mark.parametrize("n", [500, CL._CHUNK + 4321])   # + chunked path
-def test_assign_matches_bruteforce(n):
+def test_assign_matches_bruteforce(n, method, monkeypatch):
+    monkeypatch.setattr(CL, "_search_method", lambda: method)
+    for fn in (CL.assign_to_centers, CL.quantize_to_centers):
+        fn.clear_cache()                  # the method is fixed at trace time
     rng = np.random.default_rng(1)
     centers = jnp.sort(jnp.asarray(rng.normal(size=37)))
     v = jnp.asarray(rng.normal(size=n) * 2)

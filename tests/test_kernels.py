@@ -119,6 +119,40 @@ def test_lut_matmul_block_shapes(bm, bn, bk):
                                   np.asarray(ref.lut_matmul_ref(a, w, t)))
 
 
+@pytest.mark.parametrize("m,bm,id_dtype", [(2, 2, jnp.int16),
+                                            (11, 8, jnp.int16),
+                                            (5, 8, jnp.int32)])
+def test_lut_kernel_paper_table(m, bm, id_dtype):
+    """The lut kernel at the paper's 4096 × 1000 table (8 lane segments
+    per lookup) with ragged M, N and K edges: bit-exact."""
+    from repro.kernels.lut_matmul import lut_matmul_pallas
+    a = jax.random.randint(KEY, (m, 150), 0, 4096)
+    w = jax.random.randint(jax.random.fold_in(KEY, 1), (150, 200), 0, 1000)
+    t = jax.random.randint(jax.random.fold_in(KEY, 2), (4096, 1000),
+                           -(1 << 20), 1 << 20)
+    out = lut_matmul_pallas(a.astype(id_dtype), w.astype(id_dtype), t,
+                            bm=bm, bn=128, bk=64, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(ref.lut_matmul_ref(a, w, t)))
+
+
+@pytest.mark.parametrize("k,n,W,id_dtype,dtype", [
+    (300, 700, 1000, jnp.int16, jnp.float32),   # paper |W|, ragged tiles
+    (33, 129, 256, jnp.int8, jnp.bfloat16),     # negative narrow ids
+    (5, 3, 100, jnp.int32, jnp.float32)])       # dims below one tile
+def test_dequantize_matches_gather(k, n, W, id_dtype, dtype):
+    """The Pallas dequantize returns exactly the XLA gather's weights."""
+    from repro.kernels.codebook_matmul import dequantize_pallas
+    book = jax.random.normal(KEY, (W,))
+    idx = jax.random.randint(jax.random.fold_in(KEY, 1), (k, n), 0, W)
+    want = np.asarray(ops.dequantize(idx.astype(id_dtype), book, dtype))
+    got = dequantize_pallas(idx.astype(id_dtype), book, dtype=dtype, bk=64,
+                            bn=256, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(
+        want, np.asarray(book[idx].astype(dtype)))
+
+
 def test_kmeans_assign_block_sizes():
     from repro.kernels.kmeans1d import kmeans_assign_pallas
     v = jax.random.laplace(KEY, (3000,))

@@ -2,7 +2,8 @@
 
 Each test lowers and compiles one kernel with Mosaic for a *described*
 ``v5e:2x2`` topology at the widths of ``qwen3-1.7b`` (d_model 2048, d_ff
-6144, 8 KV heads of 128, int8 KV pages, int16 codebook ids).  Nothing
+6144, vocab 151936, 8 KV heads of 128, int8 KV pages, int16 codebook
+ids).  Nothing
 runs: this catches what Pallas interpret mode cannot — unsupported
 primitives, tiling violations, VMEM overruns, removed compiler APIs — at
 no chip time.  The topology is described inside a module-scoped fixture
@@ -75,6 +76,18 @@ def test_codebook_matmul_compiles(one_chip, m, k, n):
              _spec(one_chip, (N_WEIGHTS,), jnp.float32))
 
 
+@pytest.mark.parametrize("k,n,dtype", [
+    (151936, D_MODEL, jnp.float32),            # the tied lm-head
+    (D_MODEL, D_FF, jnp.bfloat16)])            # a dense-backend FFN weight
+def test_dequantize_compiles(one_chip, k, n, dtype):
+    from repro.kernels.codebook_matmul import dequantize_pallas
+
+    _compile(lambda idx, book: dequantize_pallas(idx, book, dtype=dtype,
+                                                 interpret=False),
+             _spec(one_chip, (k, n), jnp.int16),
+             _spec(one_chip, (N_WEIGHTS,), jnp.float32))
+
+
 @pytest.mark.parametrize("page_shape,dtype", [
     ((PAGE, N_KV, HEAD_DIM), jnp.int8),        # K / V pages
     ((PAGE, N_KV), jnp.float32),               # per-token-per-head scales
@@ -103,19 +116,19 @@ def test_kmeans_assign_compiles(one_chip):
              _spec(one_chip, (N_WEIGHTS,), jnp.float32))
 
 
-def test_lut_routes_to_xla_and_compiles(one_chip):
-    """The lut kernel's flat-table gather has no Mosaic lowering, so on a
-    TPU the §4 engine resolves to its XLA gather-accumulate — which must
-    itself compile at the paper's 4096 × 1000 table."""
+@pytest.mark.parametrize("m,k,n", [(2, D_MODEL, D_FF), (16, D_FF, D_MODEL)])
+def test_lut_rows_kernel_compiles(one_chip, m, k, n):
+    """The lut engine's TPU route — the row-gather kernel, with the tiles
+    autotune picks — at the paper's 4096 × 1000 table, for a decode step
+    (M = 2) and a 16-token prefill chunk."""
     from repro.kernels import autotune
-    from repro.kernels.lut_matmul import lut_matmul_xla
+    from repro.kernels.lut_matmul import lut_matmul_pallas
 
-    cfg = autotune.kernel_config("lut", 8, D_MODEL, D_FF, dtype="int32",
+    cfg = autotune.kernel_config("lut", m, k, n, dtype="int32",
                                  plat="tpu", table_shape=(4096, N_WEIGHTS))
-    assert cfg["impl"] == "xla"
-    compiled = jax.jit(lambda a, w, t: lut_matmul_xla(
-        a, w, t, kc=cfg["kc"], variant=cfg["variant"])).lower(
-        _spec(one_chip, (8, D_MODEL), jnp.int32),
-        _spec(one_chip, (D_MODEL, D_FF), jnp.int16),
-        _spec(one_chip, (4096, N_WEIGHTS), jnp.int32)).compile()
-    assert compiled.memory_analysis() is not None
+    assert cfg["impl"] == "pallas"
+    _compile(lambda a, w, t: lut_matmul_pallas(
+        a, w, t, bm=cfg["bm"], bn=cfg["bn"], bk=cfg["bk"], interpret=False),
+        _spec(one_chip, (m, k), jnp.int16),
+        _spec(one_chip, (k, n), jnp.int16),
+        _spec(one_chip, (4096, N_WEIGHTS), jnp.int32))
