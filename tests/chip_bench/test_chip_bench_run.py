@@ -1,0 +1,99 @@
+"""Whole runs without the chip: the entry point refuses to measure a
+CPU, and the harness's own run — set-up, window, metrics, the
+reference's comparison — at a tiny size on the CPU comes out correct,
+and not correct once a served token is altered where it is produced."""
+
+import json
+import os
+import pathlib
+import shutil
+import subprocess
+import sys
+import time
+
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+HERE = ROOT / "benchmarks" / "chip"
+sys.path[:0] = [str(HERE), str(pathlib.Path(__file__).resolve().parent)]
+
+from chip_bench_fixtures import tiny_cell  # noqa: E402
+from chipbench import harness  # noqa: E402
+
+
+def _env():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    return env
+
+
+def _run(cwd, *args):
+    return subprocess.run(
+        [sys.executable, "benchmarks/chip/run.py", "--workload",
+         "qwen3-1.7b.chat", "--seed", "7", "--seconds", "1", "--trace", "0",
+         *args], cwd=cwd, env=_env(), capture_output=True, text=True,
+        timeout=300)
+
+
+def test_no_tpu_means_no_result():
+    p = _run(ROOT)
+    assert p.returncode != 0
+    assert "no TPU" in p.stderr
+    assert not p.stdout.strip()
+
+
+def test_benchmark_files_alone_are_not_a_run(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    for p in json.loads((ROOT / "BENCHMARK.json").read_text())["paths"]:
+        shutil.copytree(ROOT / p, tmp_path / p,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    p = _run(tmp_path)
+    assert p.returncode != 0
+    assert not p.stdout.strip()
+
+
+@pytest.fixture(scope="module")
+def tiny(tmp_path_factory):
+    return tiny_cell(tmp_path_factory.mktemp("tiny"))
+
+
+def _tiny_run(cell):
+    return harness.run_cell(cell, 2 ** 31 + 5, 2.0, False,
+                            time.perf_counter(), require_tpu=False)
+
+
+def test_tiny_run_is_correct(tiny):
+    res = _tiny_run(tiny)
+    assert res["correct"], res["checks"]
+    assert res["attempted"] == 8 and res["failed"] == 0
+    assert {"ttft_p95_s", "itl_p95_ms", "setup_s"} <= set(res["metrics"])
+    assert list(res)[-1] == "checks"
+    gap = res["checks"]["max_logit_gap"]
+    assert gap["value"] <= gap["limit"]
+
+
+def test_an_altered_token_is_not_correct(tiny, monkeypatch):
+    from repro.serving.engine import ServeEngine
+
+    step = ServeEngine.serve_step
+
+    def altered(self, st, quantum=1):
+        toks, done = step(self, st, quantum)
+        return {b: [(t + 1 + b) % 512 for t in ts]
+                for b, ts in toks.items()}, done
+
+    monkeypatch.setattr(ServeEngine, "serve_step", altered)
+    res = _tiny_run(tiny)
+    assert not res["correct"]
+    gap = res["checks"]["max_logit_gap"]
+    assert gap["value"] > gap["limit"]
+
+
+def test_sweep_overrides_rate_and_schedule(tiny):
+    """The knee sweep's overrides of the mix: the rate sets how many
+    requests a window offers (floor(rate x seconds)), under another
+    schedule seed; the run stays correct."""
+    res = harness.run_cell(tiny, 9, 2.0, False, time.perf_counter(),
+                           require_tpu=False, rate=2.0, schedule_seed=3)
+    assert res["correct"], res["checks"]
+    assert res["attempted"] == 4 and res["failed"] == 0

@@ -1,0 +1,116 @@
+"""From a profiler trace to idle share, kernel time and the breakdown:
+on a hand-made trace with known answers, on a small trace recorded on a
+TPU v5e by the harness itself (``chip_bench_fixtures.py``: a 2-layer,
+width-128 qwen3 cell traced for 0.25 s), and on the CPU profiler's own
+output."""
+
+import pathlib
+import sys
+
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+sys.path[:0] = [str(ROOT / "benchmarks" / "chip")]
+
+from chipbench import readers, trace  # noqa: E402
+
+RECORDED = pathlib.Path(__file__).resolve().parent / "data" / \
+    "tiny_trace.json.gz"
+KERNEL = "codebook_matmul_pallas"
+MS = 1_000_000
+
+
+def _hand_made():
+    k = ("%codebook_matmul_pallas.3 = f32[16,256]{1,0} custom-call("
+         "bf16[16,128]{1,0} %x, s16[128,256]{1,0} %w)")
+    ops = [("%while.1 = (s32[]) while(%t)", 10 * MS, 40 * MS),   # encloses
+           (k, 10 * MS, 20 * MS),
+           ("%fusion.2 = bf16[16,128] fusion(f32[16,256] "
+            "%codebook_matmul_pallas.3)", 20 * MS, 40 * MS),
+           ("%fusion.7 = bf16[4] fusion()", 60 * MS, 70 * MS)]
+    spans = [("bench.window", 0, 100 * MS),
+             ("bench.serve_step", 5 * MS, 45 * MS),
+             ("bench.sched_admit", 50 * MS, 75 * MS)]
+    return trace.Trace([ops], spans)
+
+
+def test_hand_made_trace_reduces_to_known_numbers():
+    r = trace.reduce(_hand_made())
+    assert r["window_s"] == pytest.approx(0.1)
+    assert r["busy_s"] == pytest.approx(0.04)          # [10,40) + [60,70)
+    assert r["idle_share"] == pytest.approx(0.6)
+    # a consumer naming the kernel as its operand is not the kernel
+    assert [s for _, s in trace.kernel_ops(r["ops"], KERNEL)] == \
+        [pytest.approx(0.01)]
+    assert len(r["ops"]) == 4
+    ops = dict(r["device_ops"])
+    assert "while" not in ops                          # encloses others
+    assert ops == {"fusion": pytest.approx(0.03),
+                   "codebook_matmul_pallas": pytest.approx(0.01)}
+    gaps = r["idle_gaps"]
+    assert gaps[0] == ["between calls", pytest.approx(0.03)]   # [70,100)
+    assert ["bench.sched_admit", pytest.approx(0.02)] in gaps  # [40,60)
+    assert ["bench.serve_step", pytest.approx(0.0)] not in gaps
+    assert sum(g for _, g in gaps) == pytest.approx(0.06)
+
+
+def test_a_trace_needs_one_window():
+    t = _hand_made()
+    t.spans = [s for s in t.spans if s[0] != "bench.window"]
+    with pytest.raises(ValueError):
+        trace.reduce(t)
+
+
+def test_recorded_tpu_trace():
+    t = trace.load_saved(RECORDED)
+    r = trace.reduce(t)
+    assert 0 < r["busy_s"] < r["window_s"]
+    assert 0 < r["idle_share"] < 1
+    calls = trace.kernel_ops(r["ops"], KERNEL)
+    assert calls and all(s > 0 for _, s in calls)
+    assert len(r["device_ops"]) <= 10 and len(r["idle_gaps"]) <= 10
+    assert {h for h, _ in r["idle_gaps"]} <= {
+        "bench.sched_admit", "bench.serve_step", "between calls"}
+    assert all(not n.startswith("while") for n, _ in r["device_ops"])
+
+
+def test_recorded_trace_gives_a_roofline_share_under_100():
+    import json
+
+    from chipbench import counts, peaks
+
+    conf = json.loads((ROOT / "benchmarks/chip/configs/qwen3-1.7b.json")
+                      .read_text())
+    conf["hf"].update(hidden_size=128, intermediate_size=256,
+                      vocab_size=512)
+
+    class Run:
+        ops = trace.reduce(trace.load_saved(RECORDED))["ops"]
+        shape = counts.Shape.of(conf)
+
+        @classmethod
+        def kernel_ops(cls, name):
+            return trace.kernel_ops(cls.ops, name)
+
+    Run.peaks = peaks.lookup("TPU v5 lite")
+    share = readers.codebook_roofline(Run)
+    assert 0 < share < 100
+
+
+def test_cpu_profile_reads_spans_and_no_device(tmp_path):
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.window"):
+        with jax.profiler.TraceAnnotation("bench.serve_step"):
+            jnp.ones((8, 8)).sum().block_until_ready()
+    jax.profiler.stop_trace()
+    path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    t = trace.load(path)
+    assert not t.devices
+    assert [s[0] for s in t.spans] == ["bench.window", "bench.serve_step"]
+    lo, hi = trace.window(t)
+    assert lo <= t.spans[1][1] and t.spans[1][2] <= hi
