@@ -76,6 +76,7 @@ def act_quant_pallas(x: jnp.ndarray, *, kind: str, levels: int,
         out_shape=[jax.ShapeDtypeStruct(x2.shape, x.dtype),
                    jax.ShapeDtypeStruct(x2.shape, jnp.int32)],
         interpret=interpret,
+        name="act_quant_pallas",
     )(x2)
     y = y2.reshape(-1)[:n].reshape(shape)
     idx = idx2.reshape(-1)[:n].reshape(shape)

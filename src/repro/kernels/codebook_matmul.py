@@ -144,6 +144,7 @@ def codebook_matmul_pallas(x: jnp.ndarray, w_idx: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="codebook_matmul_pallas",
         **kwargs,
     )(x, w_idx, book2d)
     return out
@@ -184,6 +185,7 @@ def dequantize_pallas(w_idx: jnp.ndarray, codebook: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((k, n), dtype),
         interpret=interpret,
+        name="dequantize_pallas",
     )(w_idx, book2d)
 
 

@@ -98,6 +98,7 @@ def kmeans_assign_pallas(values: jnp.ndarray, centers: jnp.ndarray, *,
                    jax.ShapeDtypeStruct((1, kk), jnp.float32),
                    jax.ShapeDtypeStruct((1, kk), jnp.float32)],
         interpret=interpret,
+        name="kmeans_assign_pallas",
     )(vp.reshape(1, -1), bounds.reshape(1, -1))
     idx = idx[0, :n]
     sums, counts = sums[0, :k], counts[0, :k]

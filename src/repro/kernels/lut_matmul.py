@@ -131,6 +131,7 @@ def lut_matmul_pallas(a_idx: jnp.ndarray, w_idx: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name="lut_matmul_pallas",
         **kwargs,
     )(r, w_idx)
 

@@ -77,4 +77,5 @@ def page_gather_pallas(pool: jnp.ndarray, page_table: jnp.ndarray, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, P) + page_shape, pool.dtype),
         interpret=interpret,
+        name="page_gather_pallas",
     )(pt, pool)

@@ -167,6 +167,7 @@ def decode_attention(q, k, v, kv_len, exclude=None, extra_kv=None):
 # sequence whose index IS the logical position, so the masks of
 # decode_attention/chunk_attention apply unchanged.
 
+@jax.named_scope("kv_read")
 def _gather_paged_kv(k_pool, v_pool, page_table, layer, scales=None):
     """Assemble (B, P·page, KV, hd) float K/V for one layer's pool slice.
 
@@ -234,38 +235,42 @@ def attn_decode_paged(p, x, cfg: AttnConfig, *, pos, page_table, write_pid,
     scales present ⇒ int8 pages (quantize-what-you-store, DESIGN.md §4).
     With a mesh, each `model` shard owns an S-slice of every page and the
     softmax joins through two psums (``_paged_flash_shardmap``).
-    Returns (out, k_pool, v_pool, new_scales).
+    Returns (out, k_pool, v_pool, new_scales).  Device scopes: the pool
+    read is ``kv_read`` (``_gather_paged_kv``), the rest ``attention``.
     """
     B = x.shape[0]
-    q, k, v = _project_qkv(p, x, cfg, pos=pos)
-    if mesh is not None and k_pool.shape[2] % mesh.shape["model"] == 0:
-        num, denom, m_glob, k_pool, v_pool, new_scales = _paged_flash_shardmap(
-            q, k, v, k_pool, v_pool, scales, layer, page_table,
-            write_pid[:, None], write_off[:, None], valid_len, mesh,
-            dp or ("data",))
-        out = _join_fresh(q, k, v, num, denom, m_glob)
+    with jax.named_scope("attention"):
+        q, k, v = _project_qkv(p, x, cfg, pos=pos)
+        if mesh is not None and k_pool.shape[2] % mesh.shape["model"] == 0:
+            num, denom, m_glob, k_pool, v_pool, new_scales = \
+                _paged_flash_shardmap(
+                    q, k, v, k_pool, v_pool, scales, layer, page_table,
+                    write_pid[:, None], write_off[:, None], valid_len, mesh,
+                    dp or ("data",))
+            out = _join_fresh(q, k, v, num, denom, m_glob)
+            out = dense(p["wo"], out.reshape(B, 1, cfg.n_kv * cfg.groups
+                                             * cfg.hd), kind="row")
+            return out, k_pool, v_pool, new_scales
+    k_l, v_l = _gather_paged_kv(k_pool, v_pool, page_table, layer, scales)
+    with jax.named_scope("attention"):
+        out = decode_attention(q, k_l, v_l, valid_len, extra_kv=(k, v))
+        if scales is not None:
+            ks_all, vs_all = scales
+            kq, ksc = quantize_kv(k)
+            vq, vsc = quantize_kv(v)
+            ks_all = ks_all.at[layer, write_pid, write_off].set(
+                ksc[:, 0].astype(ks_all.dtype))
+            vs_all = vs_all.at[layer, write_pid, write_off].set(
+                vsc[:, 0].astype(vs_all.dtype))
+            k, v, new_scales = kq, vq, (ks_all, vs_all)
+        else:
+            new_scales = None
+        k_pool = k_pool.at[layer, write_pid, write_off].set(
+            k[:, 0].astype(k_pool.dtype))
+        v_pool = v_pool.at[layer, write_pid, write_off].set(
+            v[:, 0].astype(v_pool.dtype))
         out = dense(p["wo"], out.reshape(B, 1, cfg.n_kv * cfg.groups
                                          * cfg.hd), kind="row")
-        return out, k_pool, v_pool, new_scales
-    k_l, v_l = _gather_paged_kv(k_pool, v_pool, page_table, layer, scales)
-    out = decode_attention(q, k_l, v_l, valid_len, extra_kv=(k, v))
-    if scales is not None:
-        ks_all, vs_all = scales
-        kq, ksc = quantize_kv(k)
-        vq, vsc = quantize_kv(v)
-        ks_all = ks_all.at[layer, write_pid, write_off].set(
-            ksc[:, 0].astype(ks_all.dtype))
-        vs_all = vs_all.at[layer, write_pid, write_off].set(
-            vsc[:, 0].astype(vs_all.dtype))
-        k, v, new_scales = kq, vq, (ks_all, vs_all)
-    else:
-        new_scales = None
-    k_pool = k_pool.at[layer, write_pid, write_off].set(
-        k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[layer, write_pid, write_off].set(
-        v[:, 0].astype(v_pool.dtype))
-    out = dense(p["wo"], out.reshape(B, 1, cfg.n_kv * cfg.groups * cfg.hd),
-                kind="row")
     return out, k_pool, v_pool, new_scales
 
 
@@ -280,6 +285,7 @@ def attn_prefill_chunk(p, x, cfg: AttnConfig, *, pos, page_table, write_pid,
     the store a single dynamic_update_slice; ``write_pid`` 0 targets the
     trash page — used when the chunk's page is a shared prefix-cache hit
     recomputed only for its logits).  Returns (out, k_pool, v_pool, scales).
+    Device scopes as in ``attn_decode_paged``.
     """
     B, C, _ = x.shape
     if B != 1:
@@ -288,45 +294,50 @@ def attn_prefill_chunk(p, x, cfg: AttnConfig, *, pos, page_table, write_pid,
         raise ValueError(f"attn_prefill_chunk is batch-of-one (got B={B}); "
                          "prompts stream through chunks one request at a "
                          "time")
-    q, k, v = _project_qkv(p, x, cfg, pos=pos)
-    if mesh is not None and k_pool.shape[2] % mesh.shape["model"] == 0:
-        # the chunk is one full page: per-token write targets are the same
-        # physical page at offsets 0..C−1, so each shard keeps exactly its
-        # page slice (write_pid 0 = shared prefix-cache hit → trash)
-        pid_t = jnp.broadcast_to(jnp.asarray(write_pid, jnp.int32), (B, C))
-        off_t = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[None],
-                                 (B, C))
-        num, denom, m_glob, k_pool, v_pool, new_scales = _paged_flash_shardmap(
-            q, k, v, k_pool, v_pool, scales, layer, page_table, pid_t,
-            off_t, past_len, mesh, dp or ("data",))
-        out = _join_fresh(q, k, v, num, denom, m_glob)
+    with jax.named_scope("attention"):
+        q, k, v = _project_qkv(p, x, cfg, pos=pos)
+        if mesh is not None and k_pool.shape[2] % mesh.shape["model"] == 0:
+            # the chunk is one full page: per-token write targets are the
+            # same physical page at offsets 0..C−1, so each shard keeps
+            # exactly its page slice (write_pid 0 = shared prefix-cache hit
+            # → trash)
+            pid_t = jnp.broadcast_to(jnp.asarray(write_pid, jnp.int32),
+                                     (B, C))
+            off_t = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[None],
+                                     (B, C))
+            num, denom, m_glob, k_pool, v_pool, new_scales = \
+                _paged_flash_shardmap(
+                    q, k, v, k_pool, v_pool, scales, layer, page_table,
+                    pid_t, off_t, past_len, mesh, dp or ("data",))
+            out = _join_fresh(q, k, v, num, denom, m_glob)
+            out = dense(p["wo"], out.reshape(B, C, cfg.n_kv * cfg.groups
+                                             * cfg.hd), kind="row")
+            return out, k_pool, v_pool, new_scales
+    k_l, v_l = _gather_paged_kv(k_pool, v_pool, page_table, layer, scales)
+    with jax.named_scope("attention"):
+        out = chunk_attention(q, k_l, v_l, past_len, k, v)
+        zero = jnp.zeros((), jnp.int32)
+        if scales is not None:
+            ks_all, vs_all = scales
+            kq, ksc = quantize_kv(k)
+            vq, vsc = quantize_kv(v)
+            ks_all = jax.lax.dynamic_update_slice(
+                ks_all, ksc[:, None].astype(ks_all.dtype),
+                (layer, write_pid, zero, zero))
+            vs_all = jax.lax.dynamic_update_slice(
+                vs_all, vsc[:, None].astype(vs_all.dtype),
+                (layer, write_pid, zero, zero))
+            k, v, new_scales = kq, vq, (ks_all, vs_all)
+        else:
+            new_scales = None
+        k_pool = jax.lax.dynamic_update_slice(
+            k_pool, k[:, None].astype(k_pool.dtype),
+            (layer, write_pid, zero, zero, zero))
+        v_pool = jax.lax.dynamic_update_slice(
+            v_pool, v[:, None].astype(v_pool.dtype),
+            (layer, write_pid, zero, zero, zero))
         out = dense(p["wo"], out.reshape(B, C, cfg.n_kv * cfg.groups
                                          * cfg.hd), kind="row")
-        return out, k_pool, v_pool, new_scales
-    k_l, v_l = _gather_paged_kv(k_pool, v_pool, page_table, layer, scales)
-    out = chunk_attention(q, k_l, v_l, past_len, k, v)
-    zero = jnp.zeros((), jnp.int32)
-    if scales is not None:
-        ks_all, vs_all = scales
-        kq, ksc = quantize_kv(k)
-        vq, vsc = quantize_kv(v)
-        ks_all = jax.lax.dynamic_update_slice(
-            ks_all, ksc[:, None].astype(ks_all.dtype),
-            (layer, write_pid, zero, zero))
-        vs_all = jax.lax.dynamic_update_slice(
-            vs_all, vsc[:, None].astype(vs_all.dtype),
-            (layer, write_pid, zero, zero))
-        k, v, new_scales = kq, vq, (ks_all, vs_all)
-    else:
-        new_scales = None
-    k_pool = jax.lax.dynamic_update_slice(
-        k_pool, k[:, None].astype(k_pool.dtype),
-        (layer, write_pid, zero, zero, zero))
-    v_pool = jax.lax.dynamic_update_slice(
-        v_pool, v[:, None].astype(v_pool.dtype),
-        (layer, write_pid, zero, zero, zero))
-    out = dense(p["wo"], out.reshape(B, C, cfg.n_kv * cfg.groups * cfg.hd),
-                kind="row")
     return out, k_pool, v_pool, new_scales
 
 

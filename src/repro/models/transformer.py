@@ -490,6 +490,7 @@ def _paged_scales(cache):
     return None
 
 
+@jax.named_scope("prefill_chunk")
 def prefill_chunk(params, cfg, batch, cache, mesh=None):
     """Chunked prefill: one page-sized chunk of ONE request's prompt.
 
@@ -540,12 +541,13 @@ def prefill_chunk(params, cfg, batch, cache, mesh=None):
                 page_table=page_table, write_pid=write_pid, past_len=start,
                 k_pool=kc, v_pool=vc, layer=l, scales=sc, mesh=mesh, dp=dp)
             h = h + a
-            if "moe" in p_l:
-                y = M.moe_apply(p_l["moe"], L.rms_norm(p_l["ln2"], h),
-                                moe_cfg(cfg), mesh)
-            else:
-                y = L.swiglu(p_l["mlp"], L.rms_norm(p_l["ln2"], h),
-                             cfg.act_kind, cfg.act_levels, mesh)
+            with jax.named_scope("mlp"):
+                if "moe" in p_l:
+                    y = M.moe_apply(p_l["moe"], L.rms_norm(p_l["ln2"], h),
+                                    moe_cfg(cfg), mesh)
+                else:
+                    y = L.swiglu(p_l["mlp"], L.rms_norm(p_l["ln2"], h),
+                                 cfg.act_kind, cfg.act_levels, mesh)
         return (h + y, kc, vc, sc, l + 1, pb.state), None
 
     (x, nk, nv, nsc, _, ps1), _ = jax.lax.scan(
@@ -558,11 +560,14 @@ def prefill_chunk(params, cfg, batch, cache, mesh=None):
     if ps0:
         new_cache["probes"] = probes.bump(ps1, "tokens",
                                           length.astype(jnp.float32))
-    x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
-    x_last = L.rms_norm(params["final_norm"], x_last)
-    return _logits(params, cfg, x_last), new_cache
+    with jax.named_scope("head"):
+        x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+        x_last = L.rms_norm(params["final_norm"], x_last)
+        logits = _logits(params, cfg, x_last)
+    return logits, new_cache
 
 
+@jax.named_scope("decode_step")
 def _decode_step_paged(params, cfg, tokens, cache, mesh):
     """One paged decode step: per-slot page tables, (B,) positions.
 
@@ -603,12 +608,13 @@ def _decode_step_paged(params, cfg, tokens, cache, mesh):
                 write_pid=write_pid, write_off=write_off, valid_len=vlen,
                 k_pool=kc, v_pool=vc, layer=l, scales=sc, mesh=mesh, dp=dp)
             h = h + a
-            if "moe" in p_l:
-                y = M.moe_apply(p_l["moe"], L.rms_norm(p_l["ln2"], h),
-                                moe_cfg(cfg), mesh)
-            else:
-                y = L.swiglu(p_l["mlp"], L.rms_norm(p_l["ln2"], h),
-                             cfg.act_kind, cfg.act_levels, mesh)
+            with jax.named_scope("mlp"):
+                if "moe" in p_l:
+                    y = M.moe_apply(p_l["moe"], L.rms_norm(p_l["ln2"], h),
+                                    moe_cfg(cfg), mesh)
+                else:
+                    y = L.swiglu(p_l["mlp"], L.rms_norm(p_l["ln2"], h),
+                                 cfg.act_kind, cfg.act_levels, mesh)
         return (h + y, kc, vc, sc, l + 1, pb.state), None
 
     (x, nk, nv, nsc, _, ps1), _ = jax.lax.scan(
@@ -620,8 +626,10 @@ def _decode_step_paged(params, cfg, tokens, cache, mesh):
         new_cache.update(k_scale=nsc[0], v_scale=nsc[1])
     if ps0:
         new_cache["probes"] = probes.bump(ps1, "tokens", float(B))
-    x = L.rms_norm(params["final_norm"], x)
-    return _logits(params, cfg, x), new_cache
+    with jax.named_scope("head"):
+        x = L.rms_norm(params["final_norm"], x)
+        logits = _logits(params, cfg, x)
+    return logits, new_cache
 
 
 def _verify_step_paged(params, cfg, tokens, cache, mesh):

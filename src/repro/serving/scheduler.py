@@ -269,7 +269,10 @@ class AsyncScheduler:
             if self.telemetry.enabled:
                 self.telemetry.count("sched.arrivals")
                 self.telemetry.instant("requests", rid, "arrive")
-                self.telemetry.open_span("requests", rid, "queued")
+                # queued from the arrival itself, so a request harvested
+                # late (a router's hand-over, a long round) counts it all
+                self.telemetry.open_span("requests", rid, "queued",
+                                         t=h.arrival)
 
     def next_arrival(self) -> float | None:
         return self.pending[0][0] if self.pending else None
@@ -345,7 +348,9 @@ class AsyncScheduler:
             if tel.enabled:
                 tel.count("sched.admissions")
                 tel.span("slots", slot, "prefill", t0, self.clock.now())
-                tel.close_span("requests", h.rid, "queued")
+                # queueing ends where the placement that succeeded began:
+                # the prefill is the slot's, not the queue's
+                tel.close_span("requests", h.rid, "queued", t=t0)
                 tel.open_span("requests", h.rid, "running")
                 tel.instant("requests", h.rid, "admit")
             self._emit(h, [first])           # prefill samples token #1
@@ -525,10 +530,15 @@ class AsyncScheduler:
         queue then returns False instead of raising — a higher-priority
         arrival may yet become the head and unblock placement — and the
         caller owns starvation detection once its traffic runs out."""
+        with self.telemetry.region("sched.step"):
+            return self._step(more_arrivals)
+
+    def _step(self, more_arrivals: bool) -> bool:
         tel = self.telemetry
         t_round0 = self.clock.now()
         self._harvest()
-        placed = self._admit_ready()
+        with tel.region("sched.admit"):
+            placed = self._admit_ready()
         if tel.enabled:
             tel.observe("sched.queue_depth", len(self.ready))
             # counter-track samples (Perfetto "C" events): the load curves
@@ -562,10 +572,11 @@ class AsyncScheduler:
                     if num.get("kv_err_max"):
                         tel.counter("numerics.kv_err_max",
                                     max(num["kv_err_max"]))
+        with tel.region("sched.emit"):
             for slot in sorted(toks):
                 self._emit(self.slots[slot], toks[slot])
-        for slot in done:
-            self._finish(slot)
+            for slot in done:
+                self._finish(slot)
         if placed or toks or done:
             if tel.enabled:
                 tel.count("sched.rounds")

@@ -33,6 +33,10 @@ Three surfaces, one object:
   ``sched.pages_swapped_out`` counts *data* pages actually moved through
   the host blob (what the swap cost model bills).  ``RequestHandle`` /
   ``ServerReport`` use the same ``pages_swapped_out`` spelling.
+* **Host regions** — ``region(name)`` is a ``jax.profiler.TraceAnnotation``
+  around a stretch of the scheduler's or engine's host work, so a
+  profile shows it on the device ops' clock.  Regions are profiler-only:
+  they never enter the snapshot or the event log (DESIGN.md §13).
 * **Zero overhead when disabled** — the default wiring is
   ``NULL_TELEMETRY``, whose methods are argument-swallowing no-ops with
   ``enabled=False``; hot paths guard their aggregation work behind
@@ -52,7 +56,10 @@ kernel section is structurally present and identically zero).
 
 from __future__ import annotations
 
+import contextlib
 import json
+
+import jax
 
 __all__ = ["Telemetry", "NULL_TELEMETRY", "DEFAULT_BUCKETS", "TRACKS"]
 
@@ -174,13 +181,29 @@ class Telemetry:
         self._events.append(("X", round(t0, 9), round(t1, 9),
                              track, int(tid), name))
 
-    def open_span(self, track: str, tid: int, name: str) -> None:
-        self._open[(track, int(tid), name)] = self.clock.now()
+    def open_span(self, track: str, tid: int, name: str,
+                  t: float | None = None) -> None:
+        """Open a span at ``t`` (default: now on the injected clock)."""
+        self._open[(track, int(tid), name)] = (self.clock.now() if t is None
+                                               else t)
 
-    def close_span(self, track: str, tid: int, name: str) -> None:
+    def close_span(self, track: str, tid: int, name: str,
+                   t: float | None = None) -> None:
+        """Close an open span at ``t`` (default: now); no-op when none is
+        open."""
         t0 = self._open.pop((track, int(tid), name), None)
         if t0 is not None:
-            self.span(track, tid, name, t0, self.clock.now())
+            self.span(track, tid, name, t0,
+                      self.clock.now() if t is None else t)
+
+    def region(self, name: str, **args):
+        """A host region on the profiler's clock: a context manager that
+        is a ``jax.profiler.TraceAnnotation``, so the region lands in the
+        same trace as the device ops it dispatches or waits for.  Regions
+        never enter ``event_log()`` or ``snapshot()``: on a virtual clock
+        host work has no length, so they mean nothing in the replay
+        record."""
+        return jax.profiler.TraceAnnotation(name, **args)
 
     def instant(self, track: str, tid: int, name: str) -> None:
         self._events.append(("I", round(self.clock.now(), 9),
@@ -490,14 +513,17 @@ class _ScopedTelemetry:
     def span(self, track, tid, name, t0, t1):
         self._base.span(self._n(track), tid, name, t0, t1)
 
-    def open_span(self, track, tid, name):
-        self._base.open_span(self._n(track), tid, name)
+    def open_span(self, track, tid, name, t=None):
+        self._base.open_span(self._n(track), tid, name, t)
 
-    def close_span(self, track, tid, name):
-        self._base.close_span(self._n(track), tid, name)
+    def close_span(self, track, tid, name, t=None):
+        self._base.close_span(self._n(track), tid, name, t)
 
     def instant(self, track, tid, name):
         self._base.instant(self._n(track), tid, name)
+
+    def region(self, name, **args):
+        return self._base.region(self._n(name), **args)
 
     def counter(self, name, value):
         self._base.counter(self._n(name), value)
@@ -556,14 +582,17 @@ class _NullTelemetry:
     def span(self, track, tid, name, t0, t1):
         pass
 
-    def open_span(self, track, tid, name):
+    def open_span(self, track, tid, name, t=None):
         pass
 
-    def close_span(self, track, tid, name):
+    def close_span(self, track, tid, name, t=None):
         pass
 
     def instant(self, track, tid, name):
         pass
+
+    def region(self, name, **args):
+        return _NULL_REGION
 
     def counter(self, name, value):
         pass
@@ -586,5 +615,9 @@ class _NullTelemetry:
     def summary(self):
         return "[telemetry] disabled"
 
+
+# the one no-op context every disabled region returns: nothing is built
+# per call
+_NULL_REGION = contextlib.nullcontext()
 
 NULL_TELEMETRY = _NullTelemetry()
