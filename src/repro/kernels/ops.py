@@ -36,9 +36,10 @@ from repro.kernels import codebook_matmul as _cm
 from repro.kernels import kmeans1d as _km
 from repro.kernels import lut_matmul as _lm
 from repro.kernels import page_gather as _pg
+from repro.kernels import paged_attention as _pa
 
 __all__ = ["codebook_matmul", "dequantize", "lut_matmul", "act_quant",
-           "kmeans_assign", "gather_pages", "on_tpu",
+           "kmeans_assign", "gather_pages", "paged_attention", "on_tpu",
            "supports_compiled_pallas", "route_counts", "reset_route_counts"]
 
 # Trace-time platform-route counters: "{kernel}.{impl}" -> times that impl
@@ -180,6 +181,44 @@ def gather_pages(pool, page_table):
     # jnp.take default is 'fill', which would turn an OOB id into NaN/INT_MIN
     # rather than the bounded-garbage contract both paths promise)
     return jnp.take(pool, page_table.astype(jnp.int32), axis=0, mode="clip")
+
+
+# --- paged KV cache: attention over the live pages ---------------------------
+
+# the kernel keeps the layer's per-page scale rows in VMEM for the whole call
+_PA_SCALE_VMEM = 8 << 20
+
+
+def paged_attention(q, k_pool, v_pool, scales, layer, page_table, lengths):
+    """Partial softmax statistics of q against each slot's cached pages,
+    read in place from the stacked pool — or None where the kernel does not
+    apply, and the caller reads the pool through ``gather_pages``.
+
+    q: (B, Lq, KV, G, hd); k_pool/v_pool: (L, n_pages, page, KV, hd);
+    scales: (k_scale, v_scale) (L, n_pages, page, KV) or None; page_table:
+    (B, P); lengths: scalar or (B,) attendable past per slot.  Returns
+    ``(num (B, KV, G, Lq, hd), denom (B, KV, G, Lq), m (B, KV, G, Lq))``,
+    what ``models.attention._join_fresh`` folds the fresh tokens into.
+    The kernel (``paged_attention_pallas``) runs on TPU over int8 pages
+    whose size divides 128 with hd a multiple of 128; a float pool, or a
+    pool whose scale rows would not fit VMEM, stays on XLA.
+    """
+    B, Lq, KV, G, hd = q.shape
+    n_pages, page = k_pool.shape[1], k_pool.shape[2]
+    # VMEM pads each f32 scale row to whole 128-lane tiles
+    row_bytes = -(-page * KV // _pa.LANES) * _pa.LANES * 4
+    if not (supports_compiled_pallas() and scales is not None
+            and k_pool.dtype == jnp.int8 and hd % _pa.LANES == 0
+            and _pa.LANES % page == 0
+            and 2 * n_pages * row_bytes <= _PA_SCALE_VMEM):
+        _count_route("paged_attention", "xla")
+        return None
+    _count_route("paged_attention", "pallas")
+    qr = q.transpose(0, 2, 3, 1, 4).reshape(B, KV, G * Lq, hd)
+    num, denom, m = _pa.paged_attention_pallas(
+        qr, k_pool, v_pool, scales[0], scales[1], layer, page_table, lengths)
+    return (num.reshape(B, KV, G, Lq, hd), denom.reshape(B, KV, G, Lq),
+            m.reshape(B, KV, G, Lq))
 
 
 # --- fused activation quantization ------------------------------------------

@@ -221,11 +221,40 @@ def chunk_attention(q, k_past, v_past, past_len, k_new, v_new):
     return out.astype(q.dtype)
 
 
+def _attend_paged(q, k, v, k_pool, v_pool, page_table, layer, scales,
+                  past_len, mesh):
+    """Attention of the Lq fresh queries q (B, Lq, KV, G, hd) to each slot's
+    cached past (positions < ``past_len``) and, causally, to their own K/V
+    (B, Lq, KV, hd), read before they are written.
+
+    Without a mesh the pool is read by ``kernels.ops.paged_attention``
+    (live pages only, in place, dequantized in VMEM) whose partial softmax
+    statistics ``_join_fresh`` completes; where that kernel does not apply
+    (off TPU, float pools, a mesh), by the page gather and
+    ``decode_attention`` / ``chunk_attention``.  Device scopes: the read is
+    ``kv_read``, the rest ``attention``."""
+    from repro.kernels import ops
+
+    stats = None
+    if mesh is None:
+        with jax.named_scope("kv_read"):
+            stats = ops.paged_attention(q, k_pool, v_pool, scales, layer,
+                                        page_table, past_len)
+    if stats is not None:
+        with jax.named_scope("attention"):
+            return _join_fresh(q, k, v, *stats)
+    k_l, v_l = _gather_paged_kv(k_pool, v_pool, page_table, layer, scales)
+    with jax.named_scope("attention"):
+        if q.shape[1] == 1:
+            return decode_attention(q, k_l, v_l, past_len, extra_kv=(k, v))
+        return chunk_attention(q, k_l, v_l, past_len, k, v)
+
+
 def attn_decode_paged(p, x, cfg: AttnConfig, *, pos, page_table, write_pid,
                       write_off, valid_len, k_pool, v_pool, layer,
                       scales=None, mesh=None, dp=None):
-    """Paged decode step: gather pages, attend, scatter the token's K/V into
-    the tail page.
+    """Paged decode step: read the slot's pages, attend, scatter the token's
+    K/V into the tail page.
 
     page_table: (B, P) physical page ids per slot; write_pid/write_off: (B,)
     physical page + in-page offset receiving this token (the engine routes
@@ -236,7 +265,7 @@ def attn_decode_paged(p, x, cfg: AttnConfig, *, pos, page_table, write_pid,
     With a mesh, each `model` shard owns an S-slice of every page and the
     softmax joins through two psums (``_paged_flash_shardmap``).
     Returns (out, k_pool, v_pool, new_scales).  Device scopes: the pool
-    read is ``kv_read`` (``_gather_paged_kv``), the rest ``attention``.
+    read is ``kv_read`` (``_attend_paged``), the rest ``attention``.
     """
     B = x.shape[0]
     with jax.named_scope("attention"):
@@ -251,9 +280,9 @@ def attn_decode_paged(p, x, cfg: AttnConfig, *, pos, page_table, write_pid,
             out = dense(p["wo"], out.reshape(B, 1, cfg.n_kv * cfg.groups
                                              * cfg.hd), kind="row")
             return out, k_pool, v_pool, new_scales
-    k_l, v_l = _gather_paged_kv(k_pool, v_pool, page_table, layer, scales)
+    out = _attend_paged(q, k, v, k_pool, v_pool, page_table, layer, scales,
+                        valid_len, mesh)
     with jax.named_scope("attention"):
-        out = decode_attention(q, k_l, v_l, valid_len, extra_kv=(k, v))
         if scales is not None:
             ks_all, vs_all = scales
             kq, ksc = quantize_kv(k)
@@ -313,9 +342,9 @@ def attn_prefill_chunk(p, x, cfg: AttnConfig, *, pos, page_table, write_pid,
             out = dense(p["wo"], out.reshape(B, C, cfg.n_kv * cfg.groups
                                              * cfg.hd), kind="row")
             return out, k_pool, v_pool, new_scales
-    k_l, v_l = _gather_paged_kv(k_pool, v_pool, page_table, layer, scales)
+    out = _attend_paged(q, k, v, k_pool, v_pool, page_table, layer, scales,
+                        past_len, mesh)
     with jax.named_scope("attention"):
-        out = chunk_attention(q, k_l, v_l, past_len, k, v)
         zero = jnp.zeros((), jnp.int32)
         if scales is not None:
             ks_all, vs_all = scales

@@ -79,6 +79,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import dispatch
+from repro.kernels.paged_attention import pages_to_read
 from repro.models.model_zoo import Model
 from repro.serving import probes as nprobes
 from repro.serving.kvcache import PagePool
@@ -97,6 +98,16 @@ def _bucket(n: int, lo: int = 8) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def kv_page_counts(pos, page_table, page_size: int) -> dict:
+    """Region args of one paged forward: ``kv_pages_read``, the pages the
+    paged attention reads (each slot's pages below its ``pos``, cut at its
+    first trash id, so none for a retired all-zero row), against
+    ``kv_pages_table``, the B·P pages of the whole table."""
+    pt = np.asarray(page_table)
+    read = pages_to_read(pt, np.asarray(pos), page_size)
+    return {"kv_pages_read": int(read.sum()), "kv_pages_table": int(pt.size)}
 
 
 def _splice_rows(buf, toks, start, m):
@@ -797,13 +808,15 @@ class ServeEngine:
         is the same (1, page) shape)."""
         page = self.page_size
         plen = len(prompt)
-        row = jnp.asarray(np.asarray(adm.pids + [0] * (pool.pages_per_slot
-                                                       - len(adm.pids)),
-                                     np.int32))
+        row_np = np.asarray(adm.pids + [0] * (pool.pages_per_slot
+                                              - len(adm.pids)), np.int32)
+        row = jnp.asarray(row_np)
         logits = None
         cache = self._ps_inject(pool.cache)
         for ci, c in enumerate(range(adm.compute_from, adm.n_chunks)):
-            with self.telemetry.region("engine.prefill_chunk"):
+            args = (kv_page_counts([c * page], row_np[None], page)
+                    if self.telemetry.enabled else {})
+            with self.telemetry.region("engine.prefill_chunk", **args):
                 toks = np.zeros((1, page), np.int32)
                 chunk = prompt[c * page:(c + 1) * page]
                 toks[0, :len(chunk)] = chunk
@@ -1157,7 +1170,9 @@ class ServeEngine:
         act = st.live & (st.n_gen < st.stops)
         if not act.any():
             return {}, []
-        with self.telemetry.region("engine.decode_round"):
+        args = (kv_page_counts(st.pos, st.pt_np, self.page_size)
+                if self.paged and self.telemetry.enabled else {})
+        with self.telemetry.region("engine.decode_round", **args):
             return self._decode_round(st, act, quantum)
 
     def _decode_round(self, st: SchedState, act, quantum: int):

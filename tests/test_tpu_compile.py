@@ -132,3 +132,25 @@ def test_lut_rows_kernel_compiles(one_chip, m, k, n):
         _spec(one_chip, (m, k), jnp.int16),
         _spec(one_chip, (k, n), jnp.int16),
         _spec(one_chip, (4096, N_WEIGHTS), jnp.int32))
+
+
+@pytest.mark.parametrize("layers,batch,pages,groups,lq", [
+    (28, 64, 88, 2, 1),       # qwen3-1.7b chat: a decode step
+    (28, 1, 88, 2, PAGE),     # qwen3-1.7b chat: a prefill chunk
+    (3, 32, 112, 12, 1),      # mistral-large-123b-l3 longgen: a decode step
+])
+def test_paged_attention_compiles(one_chip, layers, batch, pages, groups,
+                                  lq):
+    """The paged-attention kernel over the cells' whole stacked int8 pools
+    (one page per slot and page-table entry, plus the trash page)."""
+    from repro.kernels.paged_attention import paged_attention_pallas
+
+    n_pages = batch * pages + 1
+    pool = _spec(one_chip, (layers, n_pages, PAGE, N_KV, HEAD_DIM), jnp.int8)
+    scale = _spec(one_chip, (layers, n_pages, PAGE, N_KV), jnp.bfloat16)
+    _compile(lambda q, k, v, ks, vs, layer, pt, lens: paged_attention_pallas(
+        q, k, v, ks, vs, layer, pt, lens),
+        _spec(one_chip, (batch, N_KV, groups * lq, HEAD_DIM), jnp.bfloat16),
+        pool, pool, scale, scale, _spec(one_chip, (), jnp.int32),
+        _spec(one_chip, (batch, pages), jnp.int32),
+        _spec(one_chip, (batch,), jnp.int32))
