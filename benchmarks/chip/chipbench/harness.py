@@ -5,18 +5,23 @@
 
 Set-up makes the configuration's index-form weights on the chip from the
 seed, builds one paged int8 ``ServeEngine`` on the codebook backend and a
-``Server`` over it, and warms the cell's shapes (an open loop serves one
-request; a closed loop fills every client's slot).  The window then offers
-the mix for ``--seconds`` seconds on the host clock.  With ``--trace 1``
+``Server`` over it (where the engine block states ``tp``, the engine is
+tensor-parallel over the cell's chips and each chip makes only its own
+shard of the weights), and warms the cell's shapes: two short requests
+served whole, then, in a closed loop, every client's slot filled and two
+rounds decoded.  The window then offers the mix for ``--seconds`` seconds
+on the host clock.  With ``--trace 1``
 the profiler records the window and the run reports the per-layer metrics;
 with ``--trace 0`` the end-to-end metrics.
 
 After the window the program's state is freed and the plain reference
-(``reference.py``) scores a seeded sample of the served requests; the
-widest gap by which a served token's logit lies below the reference's
-best is held against ``limits/<cell>.json``.  With ``--control fp8`` the
-control's widest gap is compared as well, against the same limit, and the
-run comes out not correct where it passes it.  The numbers compared go to
+(``reference.py``, or the module the configuration names) scores a
+seeded sample of the served requests; the widest gap by which a served
+token's logit lies below the reference's best is held against
+``limits/<cell>.json`` (or, where that file names it instead, the median
+gap).  With ``--control fp8`` the control's same number is compared as
+well, against the same limit, and the run comes out not correct where it
+passes it.  The numbers compared go to
 stderr as the last lines and into the result line under ``checks``.  The
 last stdout line is the result; a run that finds no TPU, or fewer chips
 than the cell asks for, prints none and exits 1.
@@ -38,6 +43,12 @@ import numpy as np
 from chipbench import counts, loadgen, peaks, spec, traffic
 from chipbench import trace as tr
 
+# tokens every warm-up request has before the window: the admission's
+# and two decode rounds', so each program has met the state its own
+# outputs give it (on a mesh the sampling key comes back from a round
+# with the mesh's sharding, and the next round and the next admission
+# compile for it)
+WARM_TOKENS = 3
 # the longest a run waits after the window for the first token of a
 # request due in it; one still without a token then has failed
 DRAIN_S = 60.0
@@ -147,6 +158,14 @@ def _check_device(chips: int, require_tpu: bool):
     return devices[:chips]
 
 
+def _hbm(devices) -> str:
+    """Bytes in use and the peak so far, on the fullest chip."""
+    stats = [d.memory_stats() or {} for d in devices]
+    use = max(int(m.get("bytes_in_use", 0)) for m in stats)
+    return (f"{use / 1e9:.3f} GB in use, peak {_peak_bytes(devices) / 1e9:.3f}"
+            " GB (fullest chip)")
+
+
 def _peak_bytes(devices) -> int:
     return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)
@@ -185,7 +204,8 @@ def serve_window(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         if schedule_seed is not None:
             mix["schedule_seed"] = schedule_seed
         traffic.check_mix(mix)
-        conf, eng_kw = cell.config, dict(cell.config["engine"])
+        conf = cell.config
+        tp, eng_kw = spec.engine_settings(cell)
         a = conf["assumed"]
         mcfg = program_config(conf)
         if traffic.max_total_len(mix) > eng_kw["max_len"]:
@@ -194,24 +214,35 @@ def serve_window(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                              f"max_len {eng_kw['max_len']}")
         model = build(mcfg)
         tree = weights.serving_tree(model, a["n_weights"])
+        mesh = shardings = None
+        if tp is not None:
+            mesh, shardings = _tp_layout(tp, tree)
         say(f"device ready, tree known at {loadgen.now() - t_start:.3f}s "
             "since start")
         params = jax.block_until_ready(weights.make_params(
-            tree, seed, a["n_weights"], a["weight_std"]))
-        say(f"weights made at {loadgen.now() - t_start:.3f}s since start")
-        engine = ServeEngine(model, params, **eng_kw)
+            tree, seed, a["n_weights"], a["weight_std"], shardings))
+        say(f"weights made at {loadgen.now() - t_start:.3f}s since start; "
+            f"{_hbm(devices)}")
+        engine = ServeEngine(model, params, mesh=mesh, **eng_kw)
         session = loadgen.Session(engine, int(mix["quantum"]))
         rng = np.random.default_rng([int(seed), 2])
         vocab = mcfg.vocab
         clients = []
+        # two short requests served whole, one after the other, first:
+        # every program runs once, and then again on the state its own
+        # outputs left
+        ready = True
+        for _ in range(2):
+            warm = traffic.Request(0.0, tuple(int(t) for t in rng.integers(
+                0, vocab, 2 * eng_kw["page_size"])), WARM_TOKENS)
+            rec = session.submit(warm, loadgen.now())
+            ready = ready and session.run_until(lambda: rec.finished,
+                                                loadgen.now() + WARM_S)
+        say(f"warm requests served at {loadgen.now() - t_start:.3f}s since "
+            f"start; {_hbm(devices)}")
         if mix["loop"] == "open":
             requests = traffic.open_loop(mix, seed, seconds, vocab)
-            warm = traffic.Request(0.0, tuple(int(t) for t in rng.integers(
-                0, vocab, 2 * eng_kw["page_size"])), 2)
-            rec = session.submit(warm, loadgen.now())
-            ready = session.run_until(lambda: rec.finished,
-                                      loadgen.now() + WARM_S)
-        else:
+        elif ready:
             waves = {}
 
             def request(c, k):
@@ -228,7 +259,7 @@ def serve_window(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             clients = [session.submit(request(c, 0), loadgen.now())
                        for c in range(int(mix["clients"]))]
             ready = session.run_until(lambda: all(
-                r.first_token is not None for r in clients),
+                len(r.token_times) >= WARM_TOKENS for r in clients),
                 loadgen.now() + WARM_S)
         if not ready:
             raise RuntimeError(f"set-up did not finish in {WARM_S}s")
@@ -237,7 +268,8 @@ def serve_window(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         say(f"set-up compile seconds {clock.total:.3f} "
             f"({clock.compiles} backend compiles)")
 
-        say(f"warm at {loadgen.now() - t_start:.3f}s since start")
+        say(f"warm at {loadgen.now() - t_start:.3f}s since start; "
+            f"{_hbm(devices)}")
         tdir = tempfile.mkdtemp(prefix="chip-bench-trace-") if trace else None
         if trace:
             opts = jax.profiler.ProfileOptions()
@@ -299,6 +331,24 @@ def serve_window(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         return run, samples
     finally:
         jax.monitoring.unregister_event_duration_listener(clock)
+
+
+def _tp_layout(tp: int, tree):
+    """The ('data', 'model') mesh of a tensor-parallel cell over its
+    ``tp`` devices, and the serving placement of ``tree`` on it — the
+    shardings the engine itself would put the weights to, so that they
+    are made in place and the engine moves nothing."""
+    import jax
+    from jax.sharding import PartitionSpec
+
+    from repro.distributed import sharding
+    from repro.launch.mesh import make_local_mesh
+
+    mesh = make_local_mesh(model=tp)
+    specs = sharding.serve_param_specs(tree)
+    return mesh, jax.tree_util.tree_map(
+        lambda s: sharding.named(mesh, s), specs,
+        is_leaf=lambda x: isinstance(x, PartitionSpec))
 
 
 def _say_load(session, t_open: float, t_close: float) -> None:
@@ -392,13 +442,22 @@ def measure(run: Run) -> dict:
     return out
 
 
+# the numbers a limits file may hold against the served tokens' gaps
+GAP_NUMBERS = {"max_logit_gap": max,
+               "median_logit_gap": lambda g: float(np.median(g))}
+
+
 def check(cell: spec.Cell, seed: int, samples, control: str | None):
     """The comparison that decides ``correct``: (correct, checks).
-    Every number in ``checks`` has to lie within its limit; with a
-    ``control`` the control's widest gap is one of them."""
-    from chipbench import reference
-
-    limit = float(cell.limits["max_logit_gap"])
+    Compared are the numbers of ``GAP_NUMBERS`` that the cell's limits
+    file names, each against its own limit; every one has to lie within
+    it.  With a ``control`` the control's same numbers are compared too."""
+    reference = spec.reference(cell)
+    limits = {k: float(cell.limits[k]) for k in GAP_NUMBERS
+              if k in cell.limits}
+    if not limits:
+        raise ValueError(f"limits of {cell.name} name none of "
+                         f"{sorted(GAP_NUMBERS)}")
     t0 = time.perf_counter()
     if not samples:
         return False, {"served_requests_scored": {"value": 0, "limit": 1}}
@@ -406,19 +465,21 @@ def check(cell: spec.Cell, seed: int, samples, control: str | None):
         cell.config, seed, samples, int(cell.limits["sample_requests"]),
         int(cell.config["engine"]["max_len"]), lower=control)
     gaps = got["served"]
-    worst = max(gaps)
     say(f"reference scored {len(samples)} requests, {len(gaps)} served "
         f"tokens in {time.perf_counter() - t0:.3f}s; gap median "
-        f"{float(np.median(gaps)):.6g}, share of tokens at the reference's "
-        f"best {float(np.mean(np.asarray(gaps) == 0)):.4f}")
+        f"{float(np.median(gaps)):.6g}, widest {max(gaps)!r}, share of "
+        f"tokens at the reference's best "
+        f"{float(np.mean(np.asarray(gaps) == 0)):.4f}")
     if control:
         cg = got["control"]
         say(f"control ({control}) widest gap {max(cg)!r}, median "
             f"{float(np.median(cg)):.6g} over {len(cg)} positions")
-    checks = {"max_logit_gap": {"value": worst, "limit": limit}}
+    checks = {k: {"value": GAP_NUMBERS[k](gaps), "limit": v}
+              for k, v in limits.items()}
     if control:
-        checks[f"control_{control}_max_logit_gap"] = {
-            "value": max(got["control"]), "limit": limit}
+        checks.update({f"control_{control}_{k}": {
+            "value": GAP_NUMBERS[k](got["control"]), "limit": v}
+            for k, v in limits.items()})
     return all(c["value"] <= c["limit"] for c in checks.values()), checks
 
 

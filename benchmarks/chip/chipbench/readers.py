@@ -44,8 +44,8 @@ def step_mfu(run):
     """Model FLOPs of the tokens the window's admissions and rounds
     computed — prompt tokens with their causal attention and one head row
     per admission; decoded tokens with attention at their context and a
-    head row each — over those calls' host seconds times peak FLOP/s,
-    in %."""
+    head row each — over those calls' host seconds times the peak FLOP/s
+    of the cell's chips, in %."""
     if run.peaks is None:
         return None
     flops = seconds = 0.0
@@ -65,4 +65,14 @@ def step_mfu(run):
         flops += counts.model_flops(run.shape, s.info["tokens"],
                                     s.info["ctx_sum"],
                                     heads=s.info["tokens"])
-    return 100.0 * flops / (seconds * run.peaks.flops) if seconds else None
+    peak = run.cell.chips * run.peaks.flops
+    return 100.0 * flops / (seconds * peak) if seconds else None
+
+
+def collective_share(run):
+    """Seconds in which a collective ran on the first device over that
+    device's busy seconds in the traced window, in %.  None without a
+    trace or where no collective ran."""
+    if run.trace is None or not run.trace["collective_s"]:
+        return None
+    return 100.0 * run.trace["collective_s"] / run.trace["first_busy_s"]

@@ -16,6 +16,12 @@ chip ran on its ``XLA Ops`` line; host planes hold the benchmark's own
   out, so nothing counts twice.
 - idle gaps: each stretch of the window with no op on the first device,
   named by the host span it falls in (``between calls`` when none).
+- collectives: the seconds of the first device's window in which a
+  collective op ran (all-reduce, all-gather, reduce-scatter,
+  collective-permute, all-to-all: by opcode, as a fusion that calls one,
+  or as the ``-start`` and ``-done`` halves of an async one), as the
+  union of their intervals, so a pair whose halves overlap counts once;
+  and that device's own busy seconds beside it.
 """
 
 from __future__ import annotations
@@ -28,6 +34,11 @@ WINDOW = "bench.window"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 _DEVICE = re.compile(r"^/device:[A-Z]+:\d+$")
+_COLL = (r"(?:all-reduce|all-gather|reduce-scatter|collective-permute|"
+         r"all-to-all)")
+# right of " = ": the opcode, with its async suffix, or a fusion's callee
+_COLLECTIVE = re.compile(rf"(?:^|[\s)}}]){_COLL}(?:-start|-done)?\(|"
+                         rf"calls=%{_COLL}")
 
 
 @dataclasses.dataclass
@@ -137,6 +148,13 @@ def _programs(modules, lo, hi) -> dict:
     return out
 
 
+def is_collective(name: str) -> bool:
+    """Whether an op's HLO text is a collective, whatever its instruction
+    is called (a ``psum`` shows as ``%psum.3 = ... all-reduce(...)``)."""
+    parts = name.split(" = ", 1)
+    return len(parts) == 2 and _COLLECTIVE.search(parts[1]) is not None
+
+
 def kernel_ops(ops, name: str) -> list:
     """The ops whose instruction name (left of `` = ``) holds ``name``, as
     (HLO text, seconds); a consumer that names the kernel as an operand
@@ -145,8 +163,9 @@ def kernel_ops(ops, name: str) -> list:
 
 
 def reduce(trace: Trace, top: int = 10) -> dict:
-    """busy_s, window_s, idle_share, the window's ops of the first device
-    as (HLO text, seconds), the device seconds of each program, and the
+    """busy_s, window_s, idle_share, the first device's busy and
+    collective seconds, the window's ops of the first device as (HLO
+    text, seconds), the device seconds of each program, and the
     breakdown."""
     lo, hi = window(trace)
     if not trace.devices:
@@ -156,6 +175,7 @@ def reduce(trace: Trace, top: int = 10) -> dict:
     window_s = (hi - lo) / 1e9
     busy_s = sum(busy) / len(busy) / 1e9
     ops0 = per_dev[0]
+    collective = busy_intervals([o for o in ops0 if is_collective(o[0])])
     fam = {}
     for n, a, b in _leaves(ops0):
         fam[_family(n)] = fam.get(_family(n), 0.0) + (b - a) / 1e9
@@ -177,6 +197,8 @@ def reduce(trace: Trace, top: int = 10) -> dict:
     return {
         "busy_s": busy_s, "window_s": window_s,
         "idle_share": 1.0 - busy_s / window_s,
+        "first_busy_s": busy[0] / 1e9,
+        "collective_s": sum(b - a for a, b in collective) / 1e9,
         "ops": [(n, (b - a) / 1e9) for n, a, b in ops0],
         "programs": sorted(([n, t] for n, t in _programs(trace.modules, lo,
                                                          hi).items()),

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 
 _BOOK_TAG = 0x7B00C
+# top-level keys whose leaves stack one entry per layer on axis 0
+STACKED = ("blocks", "enc_blocks")
 
 
 def base_key(seed: int):
@@ -43,8 +45,9 @@ def _path_key(key, path: str):
 
 
 def index_plane(key, path: str, shape, n_weights: int, dtype, layer=None):
-    """One (K, N) plane of ids in [0, n_weights): the plane of ``layer``
-    for a stacked leaf, the whole leaf otherwise."""
+    """One plane of ids in [0, n_weights) — (K, N), or (E, K, N) for an
+    expert leaf: the plane of ``layer`` for a stacked leaf, the whole leaf
+    otherwise."""
     k = _path_key(key, path)
     if layer is not None:
         k = jax.random.fold_in(k, layer)
@@ -70,7 +73,7 @@ def codebook(key, n_weights: int, std: float):
 
 
 def serving_tree(model, n_weights: int, min_size: int = 4096,
-                 stacked=("blocks", "enc_blocks")):
+                 stacked=STACKED):
     """Shapes and dtypes of ``make_params(model, n_weights=n_weights)``:
     the program's init, abstractly, with the rule of
     ``serving.to_codebook_params`` applied — every clustered matrix
@@ -106,9 +109,15 @@ def leaf_path(key_path) -> str:
                     for k in key_path)
 
 
-def make_params(tree, seed: int, n_weights: int, std: float):
+def make_params(tree, seed: int, n_weights: int, std: float,
+                shardings=None):
     """The weights of ``tree`` (from ``serving_tree``) for ``seed``, made
-    on the default device in one jitted call."""
+    in one jitted call: on the default device, or, given ``shardings`` (a
+    tree of ``Sharding`` like ``tree``), as the jit's output shardings, so
+    each device makes only its own shard.  The values do not depend on
+    the shardings: a w_idx leaf under a stacked prefix is made plane by
+    plane, each plane a function of (seed, path, layer) whatever the
+    leaf's rank, and the PRNG is partitionable."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
 
     def build(key):
@@ -116,7 +125,7 @@ def make_params(tree, seed: int, n_weights: int, std: float):
         out = []
         for kp, sds in flat:
             path, name = leaf_path(kp), leaf_path(kp[-1:])
-            if name == "w_idx" and sds.ndim == 3:         # stacked layers
+            if name == "w_idx" and leaf_path(kp[:1]) in STACKED:
                 out.append(jax.vmap(lambda layer, p=path, s=sds: index_plane(
                     key, p, s.shape[1:], n_weights, s.dtype, layer))(
                         jnp.arange(sds.shape[0])))
@@ -134,4 +143,6 @@ def make_params(tree, seed: int, n_weights: int, std: float):
                 raise ValueError(f"no seed rule for leaf {path}")
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    return jax.jit(build)(base_key(seed))
+    if shardings is None:
+        return jax.jit(build)(base_key(seed))
+    return jax.jit(build, out_shardings=shardings)(base_key(seed))

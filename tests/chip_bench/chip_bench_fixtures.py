@@ -23,9 +23,11 @@ TINY = dict(hidden_size=128, num_attention_heads=4, num_key_value_heads=2,
             num_hidden_layers=2)
 
 
-def tiny_cell(repo: pathlib.Path):
-    """A cell of a 2-layer, width-128 qwen3 under a small open loop,
-    written as files under ``repo`` and loaded from there."""
+def tiny_cell(repo: pathlib.Path, chips: int = 1,
+              engine: dict | None = None):
+    """A cell of a 2-layer, width-128 qwen3 under a small open loop on
+    ``chips`` devices, written as files under ``repo`` and loaded from
+    there; ``engine`` adds to the configuration's engine block."""
     if str(HERE) not in sys.path:
         sys.path[:0] = [str(HERE)]
     from chipbench import spec
@@ -41,7 +43,7 @@ def tiny_cell(repo: pathlib.Path):
         n_layers=2, d_model=128, n_heads=4, n_kv=2, head_dim=32, d_ff=256,
         vocab=512)}
     conf["assumed"]["padded_vocab"] = 512
-    conf["engine"].update(max_batch=4, max_len=128)
+    conf["engine"].update(max_batch=4, max_len=128, **(engine or {}))
     (root / "configs" / "tiny.json").write_text(json.dumps(conf))
     (root / "traffic" / "tinychat.json").write_text(json.dumps({
         "loop": "open", "rate_per_s": 4.0, "quantum": 1, "schedule_seed": 0,
@@ -56,7 +58,8 @@ def tiny_cell(repo: pathlib.Path):
                          "why": "x",
                          "file": "benchmarks/chip/configs/tiny.json"}]
     bench["workloads"] = [{"name": CELL, "config": "tiny",
-                           "traffic": "tinychat", "chips": 1, "why": "x"}]
+                           "traffic": "tinychat", "chips": chips,
+                           "why": "x"}]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
             m["workloads"] = [CELL]

@@ -83,7 +83,8 @@ def test_metrics_keep_to_the_contract():
 def test_every_cell_resolves(cell):
     c = spec.load_cell(cell, False)
     assert c.config["engine"]["backend"] == "codebook"
-    assert c.limits["max_logit_gap"] > 0 and c.limits["sample_requests"] > 0
+    compared = [c.limits[k] for k in harness.GAP_NUMBERS if k in c.limits]
+    assert compared and min(compared) > 0 and c.limits["sample_requests"] > 0
 
 
 def test_new_cell_mix_config_and_metric_are_found_as_files(tmp_path):
@@ -141,3 +142,30 @@ def test_new_cell_mix_config_and_metric_are_found_as_files(tmp_path):
     assert set(harness.measure(run)) == {"rounds.count.bursty"}
     with pytest.raises(KeyError):
         spec.load_cell("no.such.cell", False, repo=repo, root=root)
+
+
+def test_step_mfu_divides_by_the_cells_chips():
+    """The same window's FLOPs over four chips' peak read a quarter of
+    what they read over one."""
+    import dataclasses
+
+    from chipbench import loadgen, peaks, readers
+
+    cell = spec.load_cell("mistral-large-123b-l3.longgen", True)
+    spans = [loadgen.Span("serve_step", 0.1 * i, 0.1 * i + 0.12, {
+        "tokens": 32, "ctx_sum": 32 * 400 + 528, "iterations": 1,
+        "live": 32, "batch": 32}) for i in range(8)]
+    spans.append(loadgen.Span("sched_admit", 0.9, 1.0, {
+        "prompt_len": 100, "chunks": 7}))
+
+    def mfu(chips):
+        run = harness.Run(
+            cell=dataclasses.replace(cell, chips=chips),
+            shape=counts.Shape.of(cell.config),
+            peaks=peaks.lookup("TPU v5 lite"), page_size=16, seconds=1.0,
+            t_open=0.0, t_close=1.0, t_drained=1.0, records=[],
+            spans=spans, setup_s=1.0, memory_peak_bytes=0, trace=None)
+        return readers.step_mfu(run)
+
+    assert mfu(1) > 0
+    assert mfu(4) == pytest.approx(mfu(1) / 4, rel=1e-12)

@@ -6,6 +6,7 @@ output."""
 
 import pathlib
 import sys
+import types
 
 import pytest
 
@@ -114,3 +115,53 @@ def test_cpu_profile_reads_spans_and_no_device(tmp_path):
     assert [s[0] for s in t.spans] == ["bench.window", "bench.serve_step"]
     lo, hi = trace.window(t)
     assert lo <= t.spans[1][1] and t.spans[1][2] <= hi
+
+
+def _collective_trace():
+    """Device 0: a fusion, an async all-reduce whose -start event
+    encloses its -done, the fusion that reads its result, and a psum that
+    XLA named for its op; device 1 only computes."""
+    ops0 = [("%fusion.1 = f32[8]{0} fusion(f32[8]{0} %x), kind=kLoop, "
+             "calls=%fused_computation", 0, 10 * MS),
+            ("%all-reduce-start.1 = f32[8]{0} all-reduce-start(f32[8]{0} "
+             "%fusion.1), channel_id=1, to_apply=%add", 10 * MS, 20 * MS),
+            ("%all-reduce-done.1 = f32[8]{0} all-reduce-done(f32[8]{0} "
+             "%all-reduce-start.1)", 15 * MS, 20 * MS),
+            ("%fusion.2 = f32[8]{0} fusion(f32[8]{0} %all-reduce-done.1), "
+             "kind=kLoop, calls=%fused_computation.2", 20 * MS, 30 * MS),
+            ("%psum.3 = f32[8]{0:T(128)} all-reduce(f32[8]{0} %fusion.2), "
+             "channel_id=2, to_apply=%add", 30 * MS, 35 * MS)]
+    ops1 = [("%fusion.1 = f32[8]{0} fusion(f32[8]{0} %x)", 0, 70 * MS)]
+    return trace.Trace([ops0, ops1], [("bench.window", 0, 100 * MS)])
+
+
+def test_collectives_count_an_async_pair_once():
+    r = trace.reduce(_collective_trace())
+    assert r["busy_s"] == pytest.approx(0.0525)        # (35 + 70) / 2
+    assert r["first_busy_s"] == pytest.approx(0.035)
+    # [10, 20) for the pair (not 10 + 5), and the psum's [30, 35)
+    assert r["collective_s"] == pytest.approx(0.015)
+    run = types.SimpleNamespace(trace=r)
+    assert readers.collective_share(run) == pytest.approx(100 * 15 / 35)
+
+
+def test_no_collective_reads_nothing():
+    r = trace.reduce(trace.load_saved(RECORDED))       # one chip
+    assert r["collective_s"] == 0.0
+    assert readers.collective_share(types.SimpleNamespace(trace=r)) is None
+    assert readers.collective_share(types.SimpleNamespace(trace=None)) \
+        is None
+
+
+@pytest.mark.parametrize("text,want", [
+    ("%psum.1 = bf16[32,4096]{1,0:T(8,128)(2,1)} all-reduce(%f), "
+     "channel_id=1", True),
+    ("%fusion = bf16[32,1024]{1,0} fusion(%fusion.3), kind=kCustom, "
+     "calls=%all-reduce-scatter", True),
+    ("%ag = (bf16[2]{0}, u32[]) all-gather-start(bf16[1]{0} %x)", True),
+    ("%cp.2 = f32[4]{0} collective-permute-done(f32[4]{0} %cp.1)", True),
+    ("%fusion.2 = bf16[16]{0} fusion(f32[16]{0} %all-reduce.3)", False),
+    ("%all-reduce.3 = f32[4]{0} fusion(%x), calls=%fused_computation",
+     False)])
+def test_a_collective_is_known_by_its_opcode(text, want):
+    assert trace.is_collective(text) is want

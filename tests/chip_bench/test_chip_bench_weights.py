@@ -76,3 +76,25 @@ def test_seeds_give_different_weights():
     assert bool(jnp.any(a != b))
     with pytest.raises(ValueError):
         W.base_key(-1)
+
+
+def test_a_stacked_leaf_of_any_rank_is_made_layer_by_layer():
+    """An expert leaf (L, E, K, N) under a stacked prefix is made one
+    layer's (E, K, N) plane at a time, as the reference rebuilds it; a
+    leaf outside the stacked prefixes is made whole."""
+    tree = {"blocks": {"moe": {"w1": {
+                "w_idx": jax.ShapeDtypeStruct((3, 4, 16, 32), jnp.int16),
+                "codebook": jax.ShapeDtypeStruct((3, 1000), jnp.float32)}}},
+            "embed": {"w_idx": jax.ShapeDtypeStruct((2, 64, 16), jnp.int16),
+                      "codebook": jax.ShapeDtypeStruct((1000,),
+                                                       jnp.float32)}}
+    p = W.make_params(tree, SEED, 1000, 0.02)
+    key = W.base_key(SEED)
+    experts = p["blocks"]["moe"]["w1"]["w_idx"]
+    assert experts.shape == (3, 4, 16, 32)
+    for layer in range(3):
+        alone = W.index_plane(key, "blocks/moe/w1/w_idx", (4, 16, 32), 1000,
+                              jnp.int16, layer)
+        assert bool(jnp.all(alone == experts[layer]))
+    whole = W.index_plane(key, "embed/w_idx", (2, 64, 16), 1000, jnp.int16)
+    assert bool(jnp.all(whole == p["embed"]["w_idx"]))
